@@ -18,6 +18,7 @@ import hashlib
 import json
 import os
 import tempfile
+import zipfile
 from typing import Any
 
 import jax
@@ -135,10 +136,11 @@ def verify_checkpoint(path: str) -> bool:
     """True iff the checkpoint directory at ``path`` is complete and intact.
 
     Checks, cheapest first: manifest present and parseable, payload
-    present, payload SHA-256 matches the manifest's recorded digest
-    (legacy manifests without a digest skip this check), and the npz
-    carries every indexed leaf. A kill mid-save, a truncated payload or a
-    flipped bit all fail here instead of at (or worse, after) load time.
+    present, payload SHA-256 matches the manifest's recorded digest, and
+    the npz carries every indexed leaf. Legacy manifests without a digest
+    instead have every member read, so that the zip CRC vouches for the
+    bytes. A kill mid-save, a truncated payload or a flipped bit all fail
+    here instead of at (or worse, after) load time.
     """
     try:
         with open(os.path.join(path, MANIFEST)) as f:
@@ -151,10 +153,17 @@ def verify_checkpoint(path: str) -> bool:
             return False
         with np.load(payload) as z:
             names = set(z.files)
+            if digest is None:
+                for name in names:
+                    z[name]
         return all(f"arr_{i}" in names
                    for i in range(len(manifest["leaves"])))
-    except (OSError, ValueError, KeyError, json.JSONDecodeError):
+    except (OSError, ValueError, KeyError, EOFError, zipfile.BadZipFile):
         return False
+
+
+class StructureMismatch(ValueError):
+    """A checkpoint's leaves do not fit the target structure."""
 
 
 def load_pytree(path: str, like: Any, shardings: Any = None) -> Any:
@@ -163,7 +172,8 @@ def load_pytree(path: str, like: Any, shardings: Any = None) -> Any:
     ``like`` may hold concrete arrays or ShapeDtypeStructs; only its treedef
     and leaf dtypes are used. If ``shardings`` (a matching pytree of
     ``jax.sharding.Sharding`` or None leaves) is given, each leaf is placed
-    with that sharding — this is where elastic re-meshing happens.
+    with that sharding — this is where elastic re-meshing happens. Raises
+    :class:`StructureMismatch` when the leaf counts differ.
     """
     with open(os.path.join(path, MANIFEST)) as f:
         manifest = json.load(f)
@@ -171,7 +181,7 @@ def load_pytree(path: str, like: Any, shardings: Any = None) -> Any:
     leaves_like, treedef = jax.tree.flatten(like)
     n = len(manifest["leaves"])
     if n != len(leaves_like):
-        raise ValueError(
+        raise StructureMismatch(
             f"checkpoint has {n} leaves but target structure has "
             f"{len(leaves_like)}"
         )

@@ -25,6 +25,7 @@ from typing import Any
 import jax
 
 from repro.ckpt.io import (
+    StructureMismatch,
     save_pytree,
     load_pytree,
     load_meta,
@@ -95,9 +96,11 @@ class CheckpointManager:
 
         With ``step=None`` the manager walks committed steps newest-first,
         skipping any directory that fails integrity verification or whose
-        payload errors at load time — a kill mid-save or a corrupted write
-        costs at most one step of progress, never the run. Skipped steps
-        land in :attr:`last_skipped`. An explicit ``step`` is strict: a
+        leaves no longer match ``like`` — a kill mid-save or a corrupted
+        write costs at most one step of progress, never the run. Skipped
+        steps land in :attr:`last_skipped`. Any other load error (a device
+        or placement failure) raises: it is not damage to that step, and
+        an older step would hide it. An explicit ``step`` is strict: a
         corrupt target raises instead of silently loading garbage.
         """
         self.wait()
@@ -117,9 +120,9 @@ class CheckpointManager:
                 continue
             try:
                 return load_pytree(path, like, shardings), load_meta(path)
-            except Exception:
-                # digest said intact but the load still failed (e.g. leaf
-                # structure drift) — fall back to the next-oldest step
+            except (StructureMismatch, KeyError):
+                # digest said intact but the leaves don't fit ``like``
+                # (structure drift) — fall back to the next-oldest step
                 self.last_skipped.append(s)
         raise FileNotFoundError(
             f"no valid checkpoints under {self.root}"

@@ -518,10 +518,8 @@ class SweepRunner:
                 ops,
             )
 
-        from jax.experimental.shard_map import shard_map
-
         spec = P(mesh.axis_names)
-        wrap = lambda f: jax.jit(shard_map(  # noqa: E731
+        wrap = lambda f: jax.jit(jax.shard_map(  # noqa: E731
             f, mesh=mesh, in_specs=spec, out_specs=spec
         ))
         self._block_fn_uniform = wrap(block_uniform)

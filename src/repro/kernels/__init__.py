@@ -10,9 +10,8 @@ Kernels:
 - ``rglru``           — RG-LRU linear recurrence, sequence-tiled with carried
   state (recurrentgemma).
 - ``rwkv6``           — WKV6 recurrence with data-dependent decay.
-- ``idm``             — the simulator's per-lane lead-gap + IDM acceleration
-  (the physics hot spot the paper delegates to Webots), plus the
-  generalized multi-query lead+follower ``neighbor_kernel`` backing the
+- ``idm``             — the multi-query lead+follower ``neighbor_kernel``
+  (the physics hot spot the paper delegates to Webots) backing the
   neighborhood engine (``repro.core.neighbors``).
 """
 
@@ -20,7 +19,6 @@ from repro.kernels.ops import (
     flash_attention,
     rglru_linear_scan,
     wkv6,
-    idm_accel_kernel,
     neighbor_kernel,
 )
 
@@ -28,6 +26,5 @@ __all__ = [
     "flash_attention",
     "rglru_linear_scan",
     "wkv6",
-    "idm_accel_kernel",
     "neighbor_kernel",
 ]

@@ -1,27 +1,28 @@
-"""Neighborhood search + IDM acceleration (TPU Pallas) — the simulator's hot spot.
+"""Neighborhood search (TPU Pallas) — the simulator's hot spot.
 
 The paper's simulation engine (Webots physics + SUMO car following) reduces,
-per step, to: for every vehicle find the nearest same-lane leader, then apply
-IDM. That is an O(N²) masked min-reduction — on TPU, a tiled VPU problem.
+per step, to: for every vehicle find the nearest same-lane leader and
+follower, then apply IDM/MOBIL. That is an O(N²) masked min-reduction — on
+TPU, a tiled VPU problem.
 
-``idm_accel_kernel`` (the original, lead-only form):
-grid ``(nI, nJ)`` over (ego-tile, other-tile); the running minimum gap and
-the lead's velocity live in VMEM scratch across J tiles (minor grid dim);
-the final J step computes the IDM formula and writes accelerations.
+``neighbor_kernel`` grids ``(Q, nI, nJ)`` over (query-lane vector, ego tile,
+other tile). For each of Q per-vehicle query-lane vectors it returns lead
+**and** follower (idx, gap, has) in one launch — the ~8 per-step O(N²)
+searches of ``sim_step`` collapse into one kernel invocation per state
+snapshot. Running (gap, idx) minima for both directions live in VMEM
+scratch; ties resolve to the lowest slot index (strict-< running update +
+first-minimum within a tile), matching the jnp oracle bit-for-bit.
 
-``neighbor_kernel`` (the neighborhood engine's generalized form):
-grid ``(Q, nI, nJ)`` over (query-lane-vector, ego-tile, other-tile). For each
-of Q per-vehicle query-lane vectors it returns lead **and** follower
-(idx, gap, has) in one launch — the ~8 per-step O(N²) searches of
-``sim_step`` collapse into one kernel invocation per state snapshot. Running
-(gap, idx) minima for both directions live in VMEM scratch; ties resolve to
-the lowest slot index (strict-< running update + first-argmin within a
-tile), matching the jnp oracle bit-for-bit.
-Lead velocity is recovered with the classic two-pass-free trick: minimize a
-packed key ``gap·SCALE + rank(vel)`` — but here we simply carry both the min
-gap and an argmin-selected velocity via ``where`` updates, which the VPU
-handles natively. Vehicle count is padded to the 128-lane boundary; inactive
-slots sit at pos = −INF and never win a minimum.
+Layout (what Mosaic accepts on v5e): the pair tile is ``[BJ, BI]`` with the
+*other* vehicles on sublanes and the *egos* on lanes, so every reduction is
+over sublanes and yields a lane-dense ``[1, BI]`` row. Other-vehicle inputs
+arrive as ``[N, 1]`` columns and ego inputs as ``[1, N]`` rows, so the
+kernel never reshapes a 1-D vector into a column. Per-query arrays are
+``[Q, 1, N]`` with the query dim squeezed out of the block, so every block's
+last two dims are ``(1, BI)`` — equal to the full dim / a multiple of 128.
+Masks travel as ``int32``. Vehicle count is padded to a multiple of 8 (one
+tile) or of the block size (several tiles); padded slots are inactive and
+never win a minimum.
 """
 
 from __future__ import annotations
@@ -36,125 +37,9 @@ from jax.experimental.pallas import tpu as pltpu
 INF = 1e9
 
 
-def _idm_kernel(
-    pos_ref, vel_ref, lane_ref, act_ref,                 # ego tile [1, BI]
-    pos_j_ref, vel_j_ref, lane_j_ref, act_j_ref,         # other tile [1, BJ]
-    v0_ref, T_ref, amax_ref, bcomf_ref, s0_ref,          # ego params [1, BI]
-    acc_ref,                                             # out [1, BI]
-    gap_ref, vlead_ref,                                  # scratch [1, BI] f32
-    *,
-    veh_len: float,
-):
-    ij = pl.program_id(1)
-
-    @pl.when(ij == 0)
-    def _init():
-        gap_ref[...] = jnp.full_like(gap_ref, INF)
-        vlead_ref[...] = jnp.zeros_like(vlead_ref)
-
-    pos_i = pos_ref[0]                                   # [BI]
-    pos_j = pos_j_ref[0]                                 # [BJ]
-    dpos = pos_j[None, :] - pos_i[:, None]               # [BI, BJ]
-    ok = (
-        (lane_j_ref[0][None, :] == lane_ref[0][:, None])
-        & act_j_ref[0][None, :]
-        & act_ref[0][:, None]
-        & (dpos > 0.0)
-    )
-    d = jnp.where(ok, dpos, INF)
-    tile_min = d.min(axis=1)                             # [BI]
-    idx = d.argmin(axis=1)                               # [BI]
-    tile_vlead = jnp.take(vel_j_ref[0], idx)
-
-    better = tile_min < gap_ref[0]
-    gap_ref[0] = jnp.where(better, tile_min, gap_ref[0])
-    vlead_ref[0] = jnp.where(better, tile_vlead, vlead_ref[0])
-
-    @pl.when(ij == pl.num_programs(1) - 1)
-    def _finish():
-        vel = vel_ref[0]
-        has_lead = gap_ref[0] < INF * 0.5
-        gap = jnp.maximum(
-            jnp.where(has_lead, gap_ref[0] - veh_len, INF), 0.1
-        )
-        dv = jnp.where(has_lead, vel - vlead_ref[0], 0.0)
-        a_max = amax_ref[0]
-        s_star = s0_ref[0] + jnp.maximum(
-            0.0,
-            vel * T_ref[0]
-            + vel * dv / (2.0 * jnp.sqrt(a_max * bcomf_ref[0])),
-        )
-        acc = a_max * (
-            1.0
-            - (vel / jnp.maximum(v0_ref[0], 0.1)) ** 4
-            - (s_star / gap) ** 2
-        )
-        acc_ref[0] = acc.astype(acc_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("veh_len", "block", "interpret"))
-def idm_accel_kernel(
-    pos: jax.Array, vel: jax.Array, lane: jax.Array, active: jax.Array,
-    v0: jax.Array, T: jax.Array, a_max: jax.Array, b_comf: jax.Array,
-    s0: jax.Array,
-    *,
-    veh_len: float = 4.5,
-    block: int = 128,
-    interpret: bool = False,
-) -> jax.Array:
-    """[N] arrays → [N] accelerations. N is padded to the lane boundary."""
-    n = pos.shape[0]
-    bi = bj = min(block, max(n, 8))
-    pad = (-n) % bi
-    if pad:
-        def padf(x, fill):
-            return jnp.pad(x, (0, pad), constant_values=fill)
-
-        pos = padf(pos, -INF)
-        vel = padf(vel, 0.0)
-        lane = padf(lane, -1)
-        active = padf(active, False)
-        v0 = padf(v0, 1.0)
-        T = padf(T, 1.0)
-        a_max = padf(a_max, 1.0)
-        b_comf = padf(b_comf, 1.0)
-        s0 = padf(s0, 1.0)
-    npad = pos.shape[0]
-
-    def r1(x):
-        return x.reshape(1, npad)
-
-    ego_spec = pl.BlockSpec((1, bi), lambda i, j: (0, i))
-    oth_spec = pl.BlockSpec((1, bj), lambda i, j: (0, j))
-    kernel = functools.partial(_idm_kernel, veh_len=veh_len)
-    acc = pl.pallas_call(
-        kernel,
-        grid=(npad // bi, npad // bj),
-        in_specs=[ego_spec, ego_spec, ego_spec, ego_spec,
-                  oth_spec, oth_spec, oth_spec, oth_spec,
-                  ego_spec, ego_spec, ego_spec, ego_spec, ego_spec],
-        out_specs=pl.BlockSpec((1, bi), lambda i, j: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, npad), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((1, bi), jnp.float32),
-            pltpu.VMEM((1, bi), jnp.float32),
-        ],
-        interpret=interpret,
-    )(
-        r1(pos), r1(vel), r1(lane), r1(active),
-        r1(pos), r1(vel), r1(lane), r1(active),
-        r1(v0), r1(T), r1(a_max), r1(b_comf), r1(s0),
-    )
-    return acc[0, :n]
-
-
-# --------------------------------------------------------------------------
-# generalized multi-query lead+follower kernel (the neighborhood engine)
-# --------------------------------------------------------------------------
-
 def _neighbor_mq_kernel(
-    pos_ref, act_ref, qlane_ref,                          # ego tile [1, BI]
-    pos_j_ref, lane_j_ref, act_j_ref,                     # other tile [1, BJ]
+    pos_ref, act_ref, qlane_ref,                          # ego row [1, BI]
+    pos_j_ref, lane_j_ref, act_j_ref,                     # other col [BJ, 1]
     li_ref, lg_ref, lh_ref, fi_ref, fg_ref, fh_ref,       # out [1, BI]
     lgap_s, lidx_s, fgap_s, fidx_s,                       # scratch [1, BI]
     *,
@@ -170,36 +55,38 @@ def _neighbor_mq_kernel(
         fgap_s[...] = jnp.full_like(fgap_s, INF)
         fidx_s[...] = jnp.zeros_like(fidx_s)
 
-    pos_i = pos_ref[0]                                    # [BI]
-    pos_j = pos_j_ref[0]                                  # [BJ]
-    dpos = pos_j[None, :] - pos_i[:, None]                # [BI, BJ]
+    dpos = pos_j_ref[...] - pos_ref[...]                  # [BJ, BI] = pos_j - pos_i
     ok = (
-        (lane_j_ref[0][None, :] == qlane_ref[0][:, None])
-        & act_j_ref[0][None, :]
-        & act_ref[0][:, None]
+        (lane_j_ref[...] == qlane_ref[...])
+        & (act_j_ref[...] != 0)
+        & (act_ref[...] != 0)
     )
-    base = (ij * bj).astype(jnp.int32)
+    rows = jax.lax.broadcasted_iota(jnp.int32, dpos.shape, 0)
+    base = ij * bj
 
     def fold(d, gap_s, idx_s):
-        tile_min = d.min(axis=1)                          # [BI]
-        tile_idx = base + d.argmin(axis=1).astype(jnp.int32)
-        better = tile_min < gap_s[0]                      # ties keep lower j
-        gap_s[0] = jnp.where(better, tile_min, gap_s[0])
-        idx_s[0] = jnp.where(better, tile_idx, idx_s[0])
+        tile_min = d.min(axis=0, keepdims=True)           # [1, BI]
+        # first minimum: lowest row among the tile's minimizers
+        tile_idx = base + jnp.where(d == tile_min, rows, bj).min(
+            axis=0, keepdims=True
+        )
+        better = tile_min < gap_s[...]                    # ties keep lower j
+        gap_s[...] = jnp.where(better, tile_min, gap_s[...])
+        idx_s[...] = jnp.where(better, tile_idx, idx_s[...])
 
     fold(jnp.where(ok & (dpos > 0.0), dpos, INF), lgap_s, lidx_s)
     fold(jnp.where(ok & (dpos < 0.0), -dpos, INF), fgap_s, fidx_s)
 
     @pl.when(ij == pl.num_programs(2) - 1)
     def _finish():
-        has_l = lgap_s[0] < INF * 0.5
-        has_f = fgap_s[0] < INF * 0.5
-        lg_ref[0] = lgap_s[0] - veh_len
-        li_ref[0] = jnp.where(has_l, lidx_s[0], 0)
-        lh_ref[0] = has_l.astype(jnp.int32)
-        fg_ref[0] = fgap_s[0] - veh_len
-        fi_ref[0] = jnp.where(has_f, fidx_s[0], 0)
-        fh_ref[0] = has_f.astype(jnp.int32)
+        has_l = lgap_s[...] < INF * 0.5
+        has_f = fgap_s[...] < INF * 0.5
+        lg_ref[...] = lgap_s[...] - veh_len
+        li_ref[...] = jnp.where(has_l, lidx_s[...], 0)
+        lh_ref[...] = has_l.astype(jnp.int32)
+        fg_ref[...] = fgap_s[...] - veh_len
+        fi_ref[...] = jnp.where(has_f, fidx_s[...], 0)
+        fh_ref[...] = has_f.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("veh_len", "block", "interpret"))
@@ -217,39 +104,37 @@ def neighbor_kernel(
     Returns ``(lead_idx, lead_gap, has_lead, foll_idx, foll_gap, has_foll)``,
     each ``[Q, N]``; semantics match ``repro.core.neighbors.neighbor_info``
     bit-for-bit (absent: idx 0, gap INF − veh_len, has False).
+    ``block`` (a multiple of 128) is the tile edge once N exceeds it.
     """
     n = pos.shape[0]
     nq = query_lanes.shape[0]
-    bi = bj = min(block, max(n, 8))
-    pad = (-n) % bi
+    npad = -(-n // 8) * 8
+    if npad > block:
+        npad = -(-n // block) * block
+    bi = bj = min(block, npad)
+    pad = npad - n
+    lane = lane.astype(jnp.int32)
+    act = active.astype(jnp.int32)
+    query_lanes = query_lanes.astype(jnp.int32)
     if pad:
         pos = jnp.pad(pos, (0, pad), constant_values=-INF)
         lane = jnp.pad(lane, (0, pad), constant_values=-1)
-        active = jnp.pad(active, (0, pad), constant_values=False)
-        query_lanes = jnp.pad(query_lanes, ((0, 0), (0, pad)),
-                              constant_values=0)
-    npad = pos.shape[0]
-
-    def r1(x):
-        return x.reshape(1, npad)
+        act = jnp.pad(act, (0, pad))
+        query_lanes = jnp.pad(query_lanes, ((0, 0), (0, pad)))
 
     ego_spec = pl.BlockSpec((1, bi), lambda q, i, j: (0, i))
-    qln_spec = pl.BlockSpec((1, bi), lambda q, i, j: (q, i))
-    oth_spec = pl.BlockSpec((1, bj), lambda q, i, j: (0, j))
-    out_spec = pl.BlockSpec((1, bi), lambda q, i, j: (q, i))
+    oth_spec = pl.BlockSpec((bj, 1), lambda q, i, j: (j, 0))
+    qry_spec = pl.BlockSpec((None, 1, bi), lambda q, i, j: (q, 0, i))
     kernel = functools.partial(_neighbor_mq_kernel, veh_len=veh_len, bj=bj)
-    shp = jax.ShapeDtypeStruct
+    i32 = jax.ShapeDtypeStruct((nq, 1, npad), jnp.int32)
+    f32 = jax.ShapeDtypeStruct((nq, 1, npad), jnp.float32)
     li, lg, lh, fi, fg, fh = pl.pallas_call(
         kernel,
         grid=(nq, npad // bi, npad // bj),
-        in_specs=[ego_spec, ego_spec, qln_spec,
+        in_specs=[ego_spec, ego_spec, qry_spec,
                   oth_spec, oth_spec, oth_spec],
-        out_specs=[out_spec] * 6,
-        out_shape=[
-            shp((nq, npad), jnp.int32), shp((nq, npad), jnp.float32),
-            shp((nq, npad), jnp.int32), shp((nq, npad), jnp.int32),
-            shp((nq, npad), jnp.float32), shp((nq, npad), jnp.int32),
-        ],
+        out_specs=[qry_spec] * 6,
+        out_shape=[i32, f32, i32, i32, f32, i32],
         scratch_shapes=[
             pltpu.VMEM((1, bi), jnp.float32),
             pltpu.VMEM((1, bi), jnp.int32),
@@ -258,10 +143,15 @@ def neighbor_kernel(
         ],
         interpret=interpret,
     )(
-        r1(pos), r1(active), query_lanes,
-        r1(pos), r1(lane), r1(active),
+        pos.reshape(1, npad), act.reshape(1, npad),
+        query_lanes.reshape(nq, 1, npad),
+        pos.reshape(npad, 1), lane.reshape(npad, 1), act.reshape(npad, 1),
     )
+
+    def out(x):
+        return x[:, 0, :n]
+
     return (
-        li[:, :n], lg[:, :n], lh[:, :n].astype(bool),
-        fi[:, :n], fg[:, :n], fh[:, :n].astype(bool),
+        out(li), out(lg), out(lh).astype(bool),
+        out(fi), out(fg), out(fh).astype(bool),
     )

@@ -1,8 +1,8 @@
 """Public jit'd wrappers for the Pallas kernels (the ops layer).
 
-On CPU (this container) the kernels run with ``interpret=True``; on real TPU
-hardware the same calls compile to Mosaic. ``INTERPRET`` defaults to True
-when no TPU is present so examples/tests work everywhere.
+Off-TPU the kernels run with ``interpret=True``; on TPU hardware the same
+calls compile to Mosaic. ``interpret`` defaults to True when no TPU is
+present so examples/tests work everywhere.
 """
 
 from __future__ import annotations
@@ -12,7 +12,6 @@ import jax
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.rglru import rglru_linear_scan as _rglru
 from repro.kernels.rwkv6 import wkv6 as _wkv6
-from repro.kernels.idm import idm_accel_kernel as _idm
 from repro.kernels.idm import neighbor_kernel as _neighbor
 
 
@@ -39,15 +38,6 @@ def rglru_linear_scan(a, x, h0, *, block_s=256, block_w=512, interpret=None):
 def wkv6(r, k, v, w, u, s0, *, block_s=128, interpret=None):
     interpret = _default_interpret() if interpret is None else interpret
     return _wkv6(r, k, v, w, u, s0, block_s=block_s, interpret=interpret)
-
-
-def idm_accel_kernel(pos, vel, lane, active, v0, T, a_max, b_comf, s0,
-                     *, veh_len=4.5, block=128, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
-    return _idm(
-        pos, vel, lane, active, v0, T, a_max, b_comf, s0,
-        veh_len=veh_len, block=block, interpret=interpret,
-    )
 
 
 def neighbor_kernel(pos, lane, active, query_lanes,

@@ -80,37 +80,3 @@ def ref_wkv6(
     )
     S, ys = jax.lax.scan(step, s0.astype(jnp.float32), seq)
     return ys.swapaxes(0, 1), S
-
-
-def ref_idm_accel(
-    pos: jax.Array,     # [N]
-    vel: jax.Array,     # [N]
-    lane: jax.Array,    # [N] i32
-    active: jax.Array,  # [N] bool
-    v0: jax.Array, T: jax.Array, a_max: jax.Array,
-    b_comf: jax.Array, s0: jax.Array,
-    veh_len: float,
-) -> jax.Array:
-    """Same-lane lead search + IDM acceleration (simulator hot spot)."""
-    INF = 1e9
-    n = pos.shape[0]
-    dpos = pos[None, :] - pos[:, None]
-    eye = jnp.eye(n, dtype=bool)
-    ahead = (
-        (lane[None, :] == lane[:, None])
-        & active[None, :] & active[:, None] & ~eye & (dpos > 0)
-    )
-    lead_d = jnp.where(ahead, dpos, INF)
-    lead_idx = jnp.argmin(lead_d, axis=1)
-    has_lead = jnp.any(ahead, axis=1)
-    gap = jnp.where(has_lead, jnp.min(lead_d, axis=1) - veh_len, INF)
-    v_lead = jnp.where(has_lead, vel[lead_idx], 0.0)
-    dv = jnp.where(has_lead, vel - v_lead, 0.0)
-
-    gap = jnp.maximum(gap, 0.1)
-    s_star = s0 + jnp.maximum(
-        0.0, vel * T + vel * dv / (2.0 * jnp.sqrt(a_max * b_comf))
-    )
-    return a_max * (
-        1.0 - (vel / jnp.maximum(v0, 0.1)) ** 4 - (s_star / gap) ** 2
-    )

@@ -53,7 +53,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips/pod (TPU v5e); 2 pods adds the leading 'pod' axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_host_mesh(max_workers: int | None = None):
@@ -92,14 +94,5 @@ def instance_sharding(mesh):
 
 
 def make_abstract_mesh(shape, axes):
-    """Device-free mesh for spec validation (tests, dry-run planning).
-
-    ``jax.sharding.AbstractMesh`` changed signature across JAX releases:
-    older versions took ``(shape, axis_names)``, current ones take a single
-    ``((name, size), ...)`` tuple. Accept the classic (shape, axes) form and
-    build whichever the installed JAX wants.
-    """
-    try:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
+    """Device-free mesh for spec validation (tests, dry-run planning)."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))

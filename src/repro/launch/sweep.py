@@ -113,6 +113,63 @@ def _preparse_devices(argv: list[str]) -> int | None:
     return None
 
 
+def build_run(
+    cfg,
+    *,
+    mesh,
+    workers: int = 1,
+    fail_prob: float = 0.0,
+    hang_prob: float = 0.0,
+    straggler_prob: float = 0.0,
+    poison: tuple[int, ...] = (),
+    max_retries: int = 3,
+    ckpt_dir: str | None = None,
+    journal_path: str | None = None,
+    dataset_dir: str | None = None,
+    shard_size: int = 16,
+):
+    """Build one supervised sweep: ``(runner, run_kwargs)``.
+
+    ``runner`` is the :class:`~repro.core.sweep.SweepRunner` for ``cfg`` on
+    ``mesh`` with ``workers`` instances per device; ``run_kwargs`` holds
+    the ``faults``/``policy``/``ckpt``/``writer``/``journal`` arguments of
+    :func:`repro.core.fleet.run_supervised`. The seeded fault schedule
+    models the devices x workers grid, and the journal defaults to
+    ``<ckpt_dir>/journal.jsonl`` so a rebuilt run resumes its fleet state.
+    Calling this again with the same arguments is how a stopped sweep is
+    resumed from its checkpoint directory.
+    """
+    from repro.ckpt import CheckpointManager
+    from repro.core.fault import FaultModel
+    from repro.core.fleet import RetryPolicy, RunJournal
+    from repro.core.sweep import SweepRunner
+    from repro.data.shards import DatasetWriter
+
+    runner = SweepRunner(cfg, mesh=mesh, workers_per_device=workers)
+    faults = FaultModel.random_model(
+        n_workers=runner._n_workers(),
+        n_chunks=max(cfg.steps_per_instance // cfg.chunk_steps * 3, 8),
+        fail_prob=fail_prob,
+        hang_prob=hang_prob,
+        straggler_prob=straggler_prob,
+        poison_instances=tuple(poison),
+        seed=cfg.seed,
+    )
+    journal_path = journal_path or (
+        os.path.join(ckpt_dir, "journal.jsonl") if ckpt_dir else None
+    )
+    return runner, {
+        "faults": faults,
+        "policy": RetryPolicy(max_retries=max_retries),
+        "ckpt": CheckpointManager(ckpt_dir) if ckpt_dir else None,
+        "writer": (
+            DatasetWriter(dataset_dir, cfg, shard_size=shard_size)
+            if dataset_dir else None
+        ),
+        "journal": RunJournal(journal_path) if journal_path else None,
+    }
+
+
 def main() -> None:
     devices = _preparse_devices(sys.argv[1:])
     if devices is not None and devices >= 1 and "jax" not in sys.modules:
@@ -121,21 +178,16 @@ def main() -> None:
         force_host_device_count(devices)
 
     # heavy imports AFTER the device-count flag is in place
-    from repro.ckpt import CheckpointManager
     from repro.core.aggregate import aggregate_metrics, metrics_to_records
-    from repro.core.fault import FaultModel
-    from repro.core.fleet import (
-        RetryPolicy,
-        RunJournal,
-        format_completion_table,
-        run_supervised,
-    )
+    from repro.core.fleet import format_completion_table, run_supervised
     from repro.core.record import RecordConfig
     from repro.core.scenario import SimConfig
     from repro.core.scenarios import list_scenarios
-    from repro.core.sweep import SweepConfig, SweepRunner
-    from repro.data.shards import DatasetWriter
+    from repro.core.sweep import SweepConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
+
+    enable_compile_cache()
 
     # allow_abbrev off: the --devices pre-parse above matches exact
     # spellings only, so abbreviations must not silently bypass it
@@ -260,37 +312,22 @@ def main() -> None:
     # the mesh is the source of truth for device count; --workers adds the
     # per-device instance granularity, and the injector models the full
     # devices x workers worker grid (the paper's nodes x instances-per-node)
-    mesh = make_host_mesh(max_workers=args.devices)
-    runner = SweepRunner(cfg, mesh=mesh, workers_per_device=args.workers)
-    n_devices = int(mesh.devices.size)
-    n_workers = runner._n_workers()
     try:
         poison = tuple(
             int(p) for p in args.poison.split(",") if p.strip()
         )
     except ValueError:
         ap.error("--poison takes comma-separated integer instance ids")
-    faults = FaultModel.random_model(
-        n_workers=n_workers,
-        n_chunks=max(args.steps // args.chunk_steps * 3, 8),
-        fail_prob=args.fail_prob,
-        hang_prob=args.hang_prob,
-        straggler_prob=args.straggler_prob,
-        poison_instances=poison,
-        seed=args.seed,
+    mesh = make_host_mesh(max_workers=args.devices)
+    runner, run_kw = build_run(
+        cfg, mesh=mesh, workers=args.workers, fail_prob=args.fail_prob,
+        hang_prob=args.hang_prob, straggler_prob=args.straggler_prob,
+        poison=poison, max_retries=args.max_retries,
+        ckpt_dir=args.ckpt_dir, journal_path=args.journal,
+        dataset_dir=args.dataset_dir, shard_size=args.shard_size,
     )
-    policy = RetryPolicy(max_retries=args.max_retries)
-    journal_path = args.journal or (
-        os.path.join(args.ckpt_dir, "journal.jsonl")
-        if args.ckpt_dir else None
-    )
-    journal = RunJournal(journal_path) if journal_path else None
-    ckpt = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
-    writer = (
-        DatasetWriter(args.dataset_dir, cfg, shard_size=args.shard_size)
-        if args.dataset_dir
-        else None
-    )
+    writer = run_kw["writer"]
+    n_devices = int(mesh.devices.size)
 
     print(f"[sweep] scenarios: {', '.join(cfg.scenarios)} "
           f"({'mixed round-robin' if len(cfg.scenarios) > 1 else 'uniform'}) "
@@ -305,8 +342,7 @@ def main() -> None:
 
     t0 = time.perf_counter()
     state, info = run_supervised(
-        runner, faults, policy=policy, ckpt=ckpt, writer=writer,
-        journal=journal, pipeline=args.pipeline,
+        runner, **run_kw, pipeline=args.pipeline,
         chunk_deadline=args.chunk_deadline, on_progress=on_progress,
     )
     dt = time.perf_counter() - t0

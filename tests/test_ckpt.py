@@ -206,3 +206,77 @@ def test_legacy_digestless_manifest_still_verifies(tmp_path):
         json.dump(manifest, f)
     assert verify_checkpoint(path)
     load_pytree(path, like=_tree())
+
+
+def test_restore_skips_bit_flipped_legacy_payload(tmp_path):
+    """A digestless (legacy) step with one flipped payload bit fails
+    verification through the zip CRC, so restore falls back to the older
+    step instead of raising at load."""
+    import struct
+    import zipfile
+
+    mgr = CheckpointManager(str(tmp_path), async_write=False)
+    for s in (1, 2):
+        mgr.save(s, _tree(s))
+    path = _step_dir(tmp_path, 2)
+    mpath = os.path.join(path, MANIFEST)
+    with open(mpath) as f:
+        manifest = json.load(f)
+    del manifest["payload_sha256"]
+    with open(mpath, "w") as f:
+        json.dump(manifest, f)
+    payload = os.path.join(path, PAYLOAD)
+    with zipfile.ZipFile(payload) as zf:
+        info = zf.getinfo("arr_0.npy")
+    with open(payload, "r+b") as f:
+        f.seek(info.header_offset + 26)     # local header: name, extra lens
+        name_len, extra_len = struct.unpack("<HH", f.read(4))
+        last = (info.header_offset + 30 + name_len + extra_len
+                + info.compress_size - 1)   # last byte of the leaf's data
+        f.seek(last)
+        byte = f.read(1)[0]
+        f.seek(last)
+        f.write(bytes([byte ^ 1]))
+    assert not verify_checkpoint(path)
+    restored, meta = mgr.restore(like=_tree())
+    assert meta["step"] == 1
+    assert mgr.last_skipped == [2]
+    for a, b in zip(jax.tree.leaves(_tree(1)), jax.tree.leaves(restored)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_restore_falls_back_past_structure_drift(tmp_path):
+    """A newest step whose leaves no longer fit the target structure is
+    skipped like a corrupt one; the older step that fits restores."""
+    mgr = CheckpointManager(str(tmp_path), async_write=False)
+    mgr.save(1, _tree(1))
+    mgr.save(2, {"only": jnp.zeros(3)})
+    restored, meta = mgr.restore(like=_tree())
+    assert meta["step"] == 1
+    assert mgr.last_skipped == [2]
+    for a, b in zip(jax.tree.leaves(_tree(1)), jax.tree.leaves(restored)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("error", [
+    jax.errors.JaxRuntimeError("device lost during transfer"),
+    ValueError("sharding does not divide the array's leading dim"),
+])
+def test_restore_device_error_raises_instead_of_falling_back(tmp_path,
+                                                            monkeypatch,
+                                                            error):
+    """A device or placement failure during load is not checkpoint damage:
+    restore must raise it rather than silently resume an older step."""
+    import repro.ckpt.manager as manager
+
+    mgr = CheckpointManager(str(tmp_path), async_write=False)
+    for s in (1, 2):
+        mgr.save(s, _tree(s))
+
+    def broken_load(path, like, shardings=None):
+        raise error
+
+    monkeypatch.setattr(manager, "load_pytree", broken_load)
+    with pytest.raises(type(error), match=str(error)):
+        mgr.restore(like=_tree())
+    assert mgr.last_skipped == []

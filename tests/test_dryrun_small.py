@@ -24,7 +24,8 @@ from repro.launch.specs import input_specs, cell_is_applicable
 from repro.models import build_model
 from repro.sharding import param_shardings, batch_shardings
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 arch = "__ARCH__"
 shape_name = "__SHAPE__"

@@ -160,7 +160,8 @@ def test_elastic_remesh_noop_on_single_device():
     state = runner.init()
     state = runner.run_chunk(state)
     before = jax.tree.map(to_np, state)
-    mesh = jax.make_mesh((1,), ("workers",))
+    mesh = jax.make_mesh((1,), ("workers",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     state2 = runner.remesh(state, mesh)
     after = jax.tree.map(to_np, state2)
     for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(after)):

@@ -77,15 +77,15 @@ def test_real_lowered_program_has_allreduce():
     """psum under shard_map must surface in the parsed stats."""
     if len(jax.devices()) < 1:
         pytest.skip("no devices")
-    mesh = jax.make_mesh((1,), ("x",))
-    from jax.experimental.shard_map import shard_map
+    mesh = jax.make_mesh((1,), ("x",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     from jax.sharding import PartitionSpec as P
 
     def f(a):
         return jax.lax.psum(a, "x")
 
     g = jax.jit(
-        shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P())
+        jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P())
     )
     hlo = g.lower(jnp.ones((8, 8))).compile().as_text()
     st = collective_bytes(hlo)

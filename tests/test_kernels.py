@@ -10,13 +10,11 @@ from repro.kernels import (
     flash_attention,
     rglru_linear_scan,
     wkv6,
-    idm_accel_kernel,
 )
 from repro.kernels.ref import (
     ref_attention,
     ref_rglru,
     ref_wkv6,
-    ref_idm_accel,
 )
 
 TOL = dict(rtol=2e-2, atol=2e-3)
@@ -161,53 +159,3 @@ def test_wkv6_chunked_equals_whole():
     )
     np.testing.assert_allclose(np.asarray(s2), np.asarray(s_all),
                                rtol=1e-4, atol=1e-4)
-
-
-# --------------------------------------------------------------- idm
-
-@pytest.mark.parametrize("n,block", [(16, 128), (64, 32), (200, 128)])
-def test_idm_kernel_matches_ref(n, block):
-    ks = jax.random.split(jax.random.key(6), 4)
-    pos = jax.random.uniform(ks[0], (n,), jnp.float32, 0.0, 900.0)
-    vel = jax.random.uniform(ks[1], (n,), jnp.float32, 5.0, 35.0)
-    lane = jax.random.randint(ks[2], (n,), 0, 4)
-    active = jax.random.uniform(ks[3], (n,)) < 0.8
-    ones = jnp.ones((n,), jnp.float32)
-    args = dict(
-        v0=30.0 * ones, T=1.5 * ones, a_max=1.4 * ones,
-        b_comf=2.0 * ones, s0=2.0 * ones,
-    )
-    out = idm_accel_kernel(pos, vel, lane, active, block=block,
-                           interpret=True, **args)
-    ref = ref_idm_accel(pos, vel, lane, active, veh_len=4.5, **args)
-    act = np.asarray(active)
-    np.testing.assert_allclose(
-        np.asarray(out)[act], np.asarray(ref)[act], rtol=1e-5, atol=1e-5
-    )
-
-
-def test_idm_kernel_matches_simulator():
-    """The kernel agrees with the live simulator's accel computation."""
-    from repro.core import SimConfig, init_state, sample_scenario_params
-    from repro.core.simulator import sim_step, neighbor_info, _own_accel
-
-    cfg = SimConfig(n_slots=32)
-    sp = sample_scenario_params(jax.random.key(1), cfg)
-    st = init_state(cfg, jax.random.key(0))
-    step = jax.jit(lambda s: sim_step(s, cfg, sp))
-    for _ in range(100):
-        st, _ = step(st)
-    # reference accel from the simulator's own path (no ramp wall term)
-    out = idm_accel_kernel(
-        st.pos, st.vel, st.lane, st.active,
-        v0=st.v0, T=st.T, a_max=st.a_max, b_comf=st.b_comf, s0=st.s0,
-        veh_len=cfg.vehicle_len, interpret=True,
-    )
-    ref = ref_idm_accel(
-        st.pos, st.vel, st.lane, st.active,
-        st.v0, st.T, st.a_max, st.b_comf, st.s0, cfg.vehicle_len,
-    )
-    act = np.asarray(st.active)
-    np.testing.assert_allclose(
-        np.asarray(out)[act], np.asarray(ref)[act], rtol=1e-5, atol=1e-5
-    )
