@@ -49,6 +49,7 @@ from repro.ckpt import CheckpointManager
 from repro.ckpt.io import PAYLOAD, list_steps
 from repro.core.fault import FailureInjector, FaultModel, revert_instances
 from repro.core.sweep import SweepRunner, SweepState
+from repro.core.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -316,44 +317,49 @@ def run_supervised(
             return
         c, st, handle, events, done_frac = packet
         if ckpt is not None:
-            ckpt.save(c + 1, st)
-            if c in faults.corrupt_ckpt:
-                ckpt.wait()
-                step = _damage_checkpoint(ckpt.root)
-                events = events + [
-                    {"kind": "corrupt_ckpt", "chunk": c, "step": step}
-                ]
+            with span("fleet.ckpt", chunk=c):
+                ckpt.save(c + 1, st)
+                if c in faults.corrupt_ckpt:
+                    ckpt.wait()
+                    step = _damage_checkpoint(ckpt.root)
+                    events = events + [
+                        {"kind": "corrupt_ckpt", "chunk": c, "step": step}
+                    ]
         if writer is not None:
-            if handle is not None:
-                writer.finish_drain(handle)
-            else:
-                writer.drain(st)
-            if c in faults.corrupt_shard:
-                idx = _damage_shard(writer.root)
-                events = events + [
-                    {"kind": "corrupt_shard", "chunk": c, "shard": idx}
-                ]
-            repaired = writer.verify_shards()
+            with span("fleet.drain", chunk=c):
+                if handle is not None:
+                    writer.finish_drain(handle)
+                else:
+                    writer.drain(st)
+            with span("fleet.audit", chunk=c):
+                if c in faults.corrupt_shard:
+                    idx = _damage_shard(writer.root)
+                    events = events + [
+                        {"kind": "corrupt_shard", "chunk": c, "shard": idx}
+                    ]
+                repaired = writer.verify_shards()
             if repaired:
                 events = events + [
                     {"kind": "shard_repair", "chunk": c, "shards": repaired}
                 ]
-        for e in events:
-            _emit(e)
-        _emit({
-            "kind": "chunk", "chunk": c, "done": done_frac,
-            "quarantined": int(fleet.quarantined.sum()),
-        })
+        with span("fleet.journal", chunk=c):
+            for e in events:
+                _emit(e)
+            _emit({
+                "kind": "chunk", "chunk": c, "done": done_frac,
+                "quarantined": int(fleet.quarantined.sum()),
+            })
         if on_progress is not None:
             on_progress(c, done_frac)
 
     for _ in range(max_chunks):
-        done_host = np.asarray(jax.device_get(state.done))
-        if np.all(done_host | fleet.quarantined):
-            break
         # index fault plans and hold windows by the ABSOLUTE chunk counter
         # so a resumed run replays the same schedule (kill/resume parity)
-        c = int(jax.device_get(state.chunk))
+        with span("fleet.sync"):
+            done_host, c = jax.device_get((state.done, state.chunk))
+        done_host, c = np.asarray(done_host), int(c)
+        if np.all(done_host | fleet.quarantined):
+            break
         held = fleet.held(c)
         alive = ~done_host & ~held
         snapshot = state
@@ -391,8 +397,9 @@ def run_supervised(
                 "kind": "straggler", "chunk": c, "workers": list(slow),
             })
         if mask.any():
-            state = revert_instances(state, snapshot, mask)
-            state = state._replace(done=state.sim.t >= state.horizon)
+            with span("fleet.revert", chunk=c):
+                state = revert_instances(state, snapshot, mask)
+                state = state._replace(done=state.sim.t >= state.horizon)
             ids = np.flatnonzero(mask)
             fleet.retries[ids] += 1
             over = ids[fleet.retries[ids] > policy.max_retries]
@@ -427,7 +434,8 @@ def run_supervised(
             # chunk c is in flight on the devices; commit chunk c-1's
             # durable state (and its journal/heartbeat) while they compute
             _flush(deferred)
-        done_after = np.asarray(jax.device_get(state.done))  # sync point
+        with span("fleet.sync", chunk=c):
+            done_after = np.asarray(jax.device_get(state.done))  # sync point
         elapsed = time.monotonic() - t0
         if chunk_deadline is not None and elapsed > chunk_deadline:
             # an in-flight jax chunk can't be preempted: overruns degrade
@@ -438,10 +446,10 @@ def run_supervised(
                 "elapsed": elapsed, "deadline": chunk_deadline,
             })
         done_frac = float(done_after.mean())
-        handle = (
-            writer.begin_drain(state, done=done_after)
-            if (pipeline and writer is not None) else None
-        )
+        handle = None
+        if pipeline and writer is not None:
+            with span("fleet.drain", chunk=c):
+                handle = writer.begin_drain(state, done=done_after)
         packet = (c, state, handle, events, done_frac)
         if pipeline:
             deferred = packet
@@ -452,7 +460,8 @@ def run_supervised(
     if writer is not None:
         # idempotent close-out: anything a kill window or a shard repair
         # left unpersisted is re-drained here
-        writer.drain(state)
+        with span("fleet.drain"):
+            writer.drain(state)
 
     report = completion_report(state, fleet, runner.cfg.scenarios)
     info = {

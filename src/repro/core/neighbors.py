@@ -39,6 +39,10 @@ The engine exposes **per-lane tables**: for every lane ``l ∈ [0, L)`` and
 every vehicle ``i``, the lead/follower of ``i`` *as if it were in lane l*.
 Arbitrary per-vehicle query-lane vectors then become O(N) gathers, so one
 table build serves every pre-move query of a step.
+
+Every build and query runs under the ``neighbors`` named scope
+(``repro.core.trace.NEIGHBORS``), so a device profile attributes the
+engine's ops to it wherever it is called.
 """
 
 from __future__ import annotations
@@ -47,6 +51,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.core.trace import NEIGHBORS
 
 INF = 1e9
 
@@ -74,6 +80,7 @@ class NeighborTables(NamedTuple):
     foll_gap: jax.Array
     has_foll: jax.Array
 
+    @jax.named_scope(NEIGHBORS)
     def query(self, query_lane: jax.Array) -> Neighbors:
         """Answer a per-vehicle query-lane vector by gathering table rows."""
         cols = jnp.arange(query_lane.shape[0])
@@ -220,6 +227,7 @@ def _check_impl(impl: str) -> None:
         raise ValueError(f"neighbor_impl must be one of {IMPLS}, got {impl!r}")
 
 
+@jax.named_scope(NEIGHBORS)
 def build_tables(
     pos: jax.Array,
     lane: jax.Array,
@@ -246,6 +254,7 @@ def build_tables(
     return _pallas_tables(pos, lane, active, veh_len, n_lanes_total, interpret)
 
 
+@jax.named_scope(NEIGHBORS)
 def query_lanes(
     pos: jax.Array,
     lane: jax.Array,

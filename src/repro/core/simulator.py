@@ -65,6 +65,7 @@ from repro.core.scenario import (
     driver_params,
 )
 from repro.core.scenarios import get_scenario
+from repro.core.trace import LANE_CHANGE, LONGITUDINAL, RECORD, SPAWN
 from repro.core.scenarios.base import (  # noqa: F401  (idm_accel re-exported)
     RoadGeometry,
     Scenario,
@@ -337,22 +338,29 @@ def sim_step(
     impl = cfg.neighbor_impl
     n_lanes_total = geom.n_lanes_total
 
+    # Device phases are named scopes (``repro.core.trace.PHASES``): the
+    # neighbour engine names its own builds and queries ``neighbors``;
+    # this step names ``longitudinal``, ``lane_change`` and ``spawn``.
+
     # 1. pre-move snapshot: ONE fused neighborhood pass serves the own-lane
     #    accel, both MOBIL candidate evaluations and the scenario's
     #    lateral-rule queries (merge target, drop target, ...)
     tabs = build_tables(
         st.pos, st.lane, st.active, cfg.vehicle_len, n_lanes_total, impl
     )
-    ctx = scn.snapshot_ctx(st, cfg, geom)
     own = tabs.query(st.lane)
-    a_now = _own_accel(st, cfg, geom, scn, sp, st.lane, own, ctx)
+    with jax.named_scope(LONGITUDINAL):
+        ctx = scn.snapshot_ctx(st, cfg, geom)
+        a_now = _own_accel(st, cfg, geom, scn, sp, st.lane, own, ctx)
 
     # 2. lane changes: discretionary MOBIL, then the scenario's mandatory
     #    moves (gap-acceptance merge, forced lane-drop exit, vetoes)
-    new_lane, cooldown, n_lc = _apply_lane_changes(
-        st, cfg, geom, scn, sp, a_now, own, tabs, ctx
-    )
-    new_lane, n_forced = scn.lateral_rules(st, cfg, geom, sp, tabs, new_lane)
+    with jax.named_scope(LANE_CHANGE):
+        new_lane, cooldown, n_lc = _apply_lane_changes(
+            st, cfg, geom, scn, sp, a_now, own, tabs, ctx
+        )
+        new_lane, n_forced = scn.lateral_rules(st, cfg, geom, sp, tabs,
+                                               new_lane)
     st = st._replace(lane=new_lane, cooldown=cooldown)
 
     # 3. post-change snapshot (second and last construction): recompute
@@ -361,50 +369,54 @@ def sim_step(
         st.pos, st.lane, st.active, cfg.vehicle_len, st.lane, impl,
         n_lanes_total=n_lanes_total,
     )
-    ctx2 = scn.snapshot_ctx(st, cfg, geom)   # lanes changed: fresh snapshot
-    accel = _own_accel(st, cfg, geom, scn, sp, st.lane, nb, ctx2)
-    accel = jnp.where(st.active, accel, 0.0)
-    vel = jnp.maximum(st.vel + accel * cfg.dt, 0.0)
-    pos = st.pos + vel * cfg.dt
-    pos, vel = scn.boundary_clamp(st, cfg, geom, pos, vel)
-    st = st._replace(pos=pos, vel=vel)
+    with jax.named_scope(LONGITUDINAL):
+        # lanes changed: fresh snapshot
+        ctx2 = scn.snapshot_ctx(st, cfg, geom)
+        accel = _own_accel(st, cfg, geom, scn, sp, st.lane, nb, ctx2)
+        accel = jnp.where(st.active, accel, 0.0)
+        vel = jnp.maximum(st.vel + accel * cfg.dt, 0.0)
+        pos = st.pos + vel * cfg.dt
+        pos, vel = scn.boundary_clamp(st, cfg, geom, pos, vel)
+        st = st._replace(pos=pos, vel=vel)
 
-    # 4. collisions: follower overlapping its lead → remove follower.
-    #    Reuses the post-change lead assignment with the integrated
-    #    positions (each vehicle vs the leader it followed during this dt)
-    #    instead of a third all-pairs construction. On a ring the gap is
-    #    measured with a centered wrap so a leader crossing the seam is
-    #    not a phantom collision.
-    li2, hl2 = nb.lead_idx, nb.has_lead
-    dgap = st.pos[li2] - st.pos
-    if geom.ring:
-        half = 0.5 * geom.road_len
-        dgap = jnp.mod(dgap + half, geom.road_len) - half
-    lg2 = jnp.where(
-        hl2, dgap - cfg.vehicle_len, INF - cfg.vehicle_len
-    )
-    crashed = st.active & hl2 & (lg2 < 0.0)
-    n_crash = jnp.sum(crashed.astype(jnp.int32))
+        # 4. collisions: follower overlapping its lead → remove follower.
+        #    Reuses the post-change lead assignment with the integrated
+        #    positions (each vehicle vs the leader it followed during this
+        #    dt) instead of a third all-pairs construction. On a ring the
+        #    gap is measured with a centered wrap so a leader crossing the
+        #    seam is not a phantom collision.
+        li2, hl2 = nb.lead_idx, nb.has_lead
+        dgap = st.pos[li2] - st.pos
+        if geom.ring:
+            half = 0.5 * geom.road_len
+            dgap = jnp.mod(dgap + half, geom.road_len) - half
+        lg2 = jnp.where(
+            hl2, dgap - cfg.vehicle_len, INF - cfg.vehicle_len
+        )
+        crashed = st.active & hl2 & (lg2 < 0.0)
+        n_crash = jnp.sum(crashed.astype(jnp.int32))
 
-    # 5. exits (scenario predicate; a ring has none)
-    exited = scn.boundary_exit(st, cfg, geom)
-    n_out = jnp.sum(exited.astype(jnp.int32))
-    active = st.active & ~exited & ~crashed
-    st = st._replace(active=active, pos=jnp.where(active, st.pos, -INF))
+        # 5. exits (scenario predicate; a ring has none)
+        exited = scn.boundary_exit(st, cfg, geom)
+        n_out = jnp.sum(exited.astype(jnp.int32))
+        active = st.active & ~exited & ~crashed
+        st = st._replace(active=active, pos=jnp.where(active, st.pos, -INF))
 
-    # 6. TTC (closing pairs only)
-    dv = jnp.where(hl2, st.vel - st.vel[li2], 0.0)
-    ttc = jnp.where(
-        st.active & hl2 & (dv > 0.1), jnp.maximum(lg2, 0.0) / dv, INF
-    )
-    min_ttc = jnp.min(ttc)
+        # 6. TTC (closing pairs only)
+        dv = jnp.where(hl2, st.vel - st.vel[li2], 0.0)
+        ttc = jnp.where(
+            st.active & hl2 & (dv > 0.1), jnp.maximum(lg2, 0.0) / dv, INF
+        )
+        min_ttc = jnp.min(ttc)
 
-    # 7. scenario congestion gauge (ramp blockage, drop blockage, stopped
-    #    vehicles, zone occupancy — reported in the ramp_blocked_steps slot)
-    n_blocked = scn.boundary_gauge(st, cfg, geom)
+        # 7. scenario congestion gauge (ramp blockage, drop blockage,
+        #    stopped vehicles, zone occupancy — reported in the
+        #    ramp_blocked_steps slot)
+        n_blocked = scn.boundary_gauge(st, cfg, geom)
 
     # 8. demand (scenario decides spawn lanes/rates)
-    st, n_spawn = _spawn(st, cfg, geom, scn, sp, k_spawn)
+    with jax.named_scope(SPAWN):
+        st, n_spawn = _spawn(st, cfg, geom, scn, sp, k_spawn)
     st = st._replace(t=st.t + 1)
 
     delta = SimMetrics(
@@ -500,7 +512,8 @@ def rollout_chunk_rec(
             (st, m), _ = jax.lax.scan(step_body, (st, m), None, length=stride)
             # an instance frozen at its horizon for the whole window must
             # not re-emit its final row every subsequent window
-            tr = record_step(tr, st, m, rec, st.t > t0)
+            with jax.named_scope(RECORD):
+                tr = record_step(tr, st, m, rec, st.t > t0)
             return (st, m, tr), None
 
         (st, metrics, trace), _ = jax.lax.scan(
@@ -515,7 +528,8 @@ def rollout_chunk_rec(
         m2 = _acc(m, d)
         # off-stride and not-live writes drop; live re-writes after a
         # revert reproduce identical rows (determinism)
-        tr = record_step(tr, st2, m2, rec, live)
+        with jax.named_scope(RECORD):
+            tr = record_step(tr, st2, m2, rec, live)
         st = jax.tree.map(lambda a, b: jnp.where(live, b, a), st, st2)
         m = jax.tree.map(lambda a, b: jnp.where(live, b, a), m, m2)
         return (st, m, tr), None

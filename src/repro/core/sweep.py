@@ -82,6 +82,7 @@ from repro.core.simulator import (
     init_state,
     rollout_chunk_rec,
 )
+from repro.core.trace import count, span
 
 DISPATCH_MODES = ("auto", "switch", "grouped")
 
@@ -620,7 +621,8 @@ class SweepRunner:
         the wrong scenario's physics, so reject it loudly (it only happens
         on config drift at restore time).
         """
-        done, sids = jax.device_get((state.done, state.scenario_id))
+        with span("sweep.sync"):
+            done, sids = jax.device_get((state.done, state.scenario_id))
         done, sids = np.asarray(done), np.asarray(sids)
         if sids.size and (sids.min() < 0 or sids.max() >= len(self._sims)):
             raise ValueError(
@@ -643,21 +645,23 @@ class SweepRunner:
             return [GroupPlan(roster=-1, take=np.arange(n), keep=n,
                               identity=True)]
         done, sids = self._host_bitmap(state)
-        return plan_chunk(done, sids, self._n_workers(),
-                          grouped=grouped, compaction=cfg.compaction,
-                          hold=hold)
+        with span("sweep.plan"):
+            return plan_chunk(done, sids, self._n_workers(),
+                              grouped=grouped, compaction=cfg.compaction,
+                              hold=hold)
 
     def plan_chunk_sharded(
         self, state: SweepState, hold: np.ndarray | None = None
     ) -> BlockPlan | None:
         """The D>1 plan: per-device LPT blocks (:func:`plan_chunk_blocks`)."""
         done, sids = self._host_bitmap(state)
-        return plan_chunk_blocks(
-            done, sids, self.n_devices, self.workers_per_device,
-            grouped=self.dispatch == "grouped",
-            compaction=self.cfg.compaction,
-            hold=hold,
-        )
+        with span("sweep.plan"):
+            return plan_chunk_blocks(
+                done, sids, self.n_devices, self.workers_per_device,
+                grouped=self.dispatch == "grouped",
+                compaction=self.cfg.compaction,
+                hold=hold,
+            )
 
     def run_chunk(
         self, state: SweepState, hold: np.ndarray | None = None
@@ -677,15 +681,21 @@ class SweepRunner:
         live set is empty (everything done, quarantined or held) is a
         counter-only no-op.
         """
-        if self.n_devices > 1:
-            bp = self.plan_chunk_sharded(state, hold)
-            if bp is not None:
-                state = self._run_block(state, bp)
-        else:
-            for plan in self.plan_chunk(state, hold):
-                state = self._run_group(state, plan)
-        done = state.sim.t >= state.horizon
-        return state._replace(done=done, chunk=state.chunk + 1)
+        with span("sweep.chunk"):
+            if self.n_devices > 1:
+                bp = self.plan_chunk_sharded(state, hold)
+                if bp is not None:
+                    state = self._run_block(state, bp)
+            else:
+                for plan in self.plan_chunk(state, hold):
+                    state = self._run_group(state, plan)
+            done = state.sim.t >= state.horizon
+            return state._replace(done=done, chunk=state.chunk + 1)
+
+    def _count_slot_steps(self, rows: int) -> None:
+        """Counter ``sweep.slot_steps``: what one group or block computes."""
+        count("sweep.slot_steps",
+              rows * self.cfg.chunk_steps * self.cfg.sim.n_slots)
 
     def _run_block(self, state: SweepState, bp: BlockPlan) -> SweepState:
         """Gather per-device blocks, run ONE sharded call, scatter back.
@@ -694,40 +704,44 @@ class SweepRunner:
         the chunk's only data movement; inside the ``shard_map`` call each
         device steps its own rows with zero collectives.
         """
-        take = jnp.asarray(bp.take)
-        if bp.identity:
-            sub = (state.sim, state.metrics, state.params, state.horizon,
-                   state.trace)
-            row_sid = state.scenario_id
-        else:
-            sub = jax.tree.map(
-                lambda x: x[take],
-                (state.sim, state.metrics, state.params, state.horizon,
-                 state.trace),
-            )
-            row_sid = state.scenario_id[take]
-        sub = jax.device_put(sub, self.sharding)
-        row_sid = jax.device_put(row_sid, self.sharding)
-        bsid = jax.device_put(jnp.asarray(bp.block_sid), self.sharding)
+        self._count_slot_steps(bp.take.size)
+        with span("sweep.gather"):
+            take = jnp.asarray(bp.take)
+            if bp.identity:
+                sub = (state.sim, state.metrics, state.params, state.horizon,
+                       state.trace)
+                row_sid = state.scenario_id
+            else:
+                sub = jax.tree.map(
+                    lambda x: x[take],
+                    (state.sim, state.metrics, state.params, state.horizon,
+                     state.trace),
+                )
+                row_sid = state.scenario_id[take]
+            sub = jax.device_put(sub, self.sharding)
+            row_sid = jax.device_put(row_sid, self.sharding)
+            bsid = jax.device_put(jnp.asarray(bp.block_sid), self.sharding)
         fn = (
             self._block_fn_full
             if (bp.block_sid < 0).any()
             else self._block_fn_uniform
         )
-        sim, metrics, trace = fn(*sub, row_sid, bsid)
+        with span("sweep.step"):
+            sim, metrics, trace = fn(*sub, row_sid, bsid)
         if bp.identity:
             return state._replace(sim=sim, metrics=metrics, trace=trace)
-        kept = jnp.asarray(np.flatnonzero(bp.keep))
-        upd = jnp.asarray(bp.take[bp.keep])
+        with span("sweep.scatter"):
+            kept = jnp.asarray(np.flatnonzero(bp.keep))
+            upd = jnp.asarray(bp.take[bp.keep])
 
-        def scatter(full, part):
-            return full.at[upd].set(part[kept])
+            def scatter(full, part):
+                return full.at[upd].set(part[kept])
 
-        return state._replace(
-            sim=jax.tree.map(scatter, state.sim, sim),
-            metrics=jax.tree.map(scatter, state.metrics, metrics),
-            trace=jax.tree.map(scatter, state.trace, trace),
-        )
+            return state._replace(
+                sim=jax.tree.map(scatter, state.sim, sim),
+                metrics=jax.tree.map(scatter, state.metrics, metrics),
+                trace=jax.tree.map(scatter, state.trace, trace),
+            )
 
     def _run_group(self, state: SweepState, plan: GroupPlan) -> SweepState:
         """Gather one plan group, step it, scatter results to logical slots.
@@ -738,35 +752,40 @@ class SweepRunner:
         recording dispatch-agnostic by construction.
         """
         fn = self._chunk_fn if plan.roster < 0 else self._roster_fns[plan.roster]
+        self._count_slot_steps(plan.take.size)
         if plan.identity:
             args = (state.sim, state.metrics, state.params, state.horizon,
                     state.trace)
-            sim, metrics, trace = (
-                fn(*args, state.scenario_id) if plan.roster < 0 else fn(*args)
-            )
+            with span("sweep.step"):
+                sim, metrics, trace = (
+                    fn(*args, state.scenario_id) if plan.roster < 0
+                    else fn(*args)
+                )
             return state._replace(sim=sim, metrics=metrics, trace=trace)
-        take = jnp.asarray(plan.take)
-        sub = jax.tree.map(
-            lambda x: x[take],
-            (state.sim, state.metrics, state.params, state.horizon,
-             state.trace),
-        )
-        if plan.roster < 0:
-            sim, metrics, trace = self._chunk_fn(*sub, state.scenario_id[take])
-        else:
+        with span("sweep.gather"):
+            take = jnp.asarray(plan.take)
+            sub = jax.tree.map(
+                lambda x: x[take],
+                (state.sim, state.metrics, state.params, state.horizon,
+                 state.trace),
+            )
+            if plan.roster < 0:
+                sub = (*sub, state.scenario_id[take])
+        with span("sweep.step"):
             sim, metrics, trace = fn(*sub)
-        # drop padding rows, scatter results back to logical slots
-        keep = plan.keep
-        upd = jnp.asarray(plan.take[:keep])
+        with span("sweep.scatter"):
+            # drop padding rows, scatter results back to logical slots
+            keep = plan.keep
+            upd = jnp.asarray(plan.take[:keep])
 
-        def scatter(full, part):
-            return full.at[upd].set(part[:keep])
+            def scatter(full, part):
+                return full.at[upd].set(part[:keep])
 
-        return state._replace(
-            sim=jax.tree.map(scatter, state.sim, sim),
-            metrics=jax.tree.map(scatter, state.metrics, metrics),
-            trace=jax.tree.map(scatter, state.trace, trace),
-        )
+            return state._replace(
+                sim=jax.tree.map(scatter, state.sim, sim),
+                metrics=jax.tree.map(scatter, state.metrics, metrics),
+                trace=jax.tree.map(scatter, state.trace, trace),
+            )
 
     # ---------------- full run with fault handling ----------------
 

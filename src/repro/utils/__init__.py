@@ -5,7 +5,6 @@ from repro.utils.tree import (
     tree_map_with_path,
 )
 from repro.utils.hlo import collective_bytes, CollectiveStats
-from repro.utils.timing import Timer
 
 __all__ = [
     "tree_bytes",
@@ -14,5 +13,4 @@ __all__ = [
     "tree_map_with_path",
     "collective_bytes",
     "CollectiveStats",
-    "Timer",
 ]
