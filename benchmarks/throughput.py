@@ -1,6 +1,6 @@
 """Paper Table 5.1 / Figure 5.1 — sample simulation throughput.
 
-Two layers of validation:
+Three layers of validation:
 1. **Schedule accounting** — reproduce the paper's exact numbers: 48·t
    completed runs per 15-minute slice on the cluster vs a 9.73-min/run
    personal computer, 2304 vs 74 after 12 h (31× speedup).
@@ -8,6 +8,9 @@ Two layers of validation:
    48-wide vmapped batch (the per-node 8× of the paper collapses into the
    batch axis on an accelerator): veh-steps/s and the batch-over-serial
    speedup, plus the projected 12-hour run count for the paper's cluster.
+3. **Neighbour engines** — per-step time of each engine against the slot
+   count, 48 instances at once; on a TPU this is the table that decides
+   what ``neighbor_impl="auto"`` picks there.
 """
 
 from __future__ import annotations
@@ -26,10 +29,13 @@ from repro.core.metrics import (
     speedup_at,
 )
 from repro.core.scenario import SimConfig, sample_scenario_params
-from repro.core.simulator import rollout
+from repro.core.simulator import SimMetrics, init_state, rollout, rollout_chunk
 
 STEPS = 600
 N_BATCH = 48
+ENGINE_STEPS = 20
+ENGINE_SLOTS = (48, 128, 512)
+ENGINE_SLOTS_TPU = (64, 128, 256, 512, 1024, 2048)
 
 
 def run() -> None:
@@ -78,26 +84,33 @@ def run() -> None:
         f"runs_per_12h_this_host={int(12*3600/ (tn / N_BATCH)):,}",
     )
 
-    # ---- 3. neighborhood engine: per-implementation step rate ----------
+    # ---- 3. neighborhood engine: per-implementation step time ---------
     # "reference" is the per-query O(N²) scan family the seed used; dense /
-    # sort / pallas are the fused engine paths (repro.core.neighbors).
+    # sort / pallas are the fused engine paths (repro.core.neighbors). Each
+    # row times one vmapped ENGINE_STEPS-step rollout_chunk of N_BATCH
+    # highway_merge instances with the engine pinned. On a TPU the slot
+    # counts are the chip table behind neighbors.TPU_DENSE_MAX_SLOTS.
+    on_tpu = jax.default_backend() == "tpu"
     impls = ["reference", "dense", "sort"]
-    if jax.default_backend() == "tpu":
+    if on_tpu:
         impls.append("pallas")   # interpret mode off-TPU is not a timing
-    for n_slots in (48, 128, 512):
+    keys = jax.random.split(jax.random.key(1), N_BATCH)
+    horizon = jnp.full((N_BATCH,), 1 << 30, jnp.int32)
+    for n_slots in ENGINE_SLOTS_TPU if on_tpu else ENGINE_SLOTS:
         base = None
         for impl in impls:
             icfg = SimConfig(n_slots=n_slots, neighbor_impl=impl)
-            isp = sample_scenario_params(jax.random.key(1), icfg)
-            # key passed at call time so XLA cannot constant-fold the run
-            roll = jax.jit(
-                lambda k, icfg=icfg, isp=isp: rollout(k, icfg, isp, STEPS)
-            )
-            t = timeit(roll, jax.random.key(0))
+            sp = jax.vmap(lambda k: sample_scenario_params(k, icfg))(keys)
+            st = jax.vmap(lambda k: init_state(icfg, k))(keys)
+            met = jax.vmap(lambda _: SimMetrics.zeros())(keys)
+            chunk = jax.jit(jax.vmap(
+                lambda s, m, p, h, icfg=icfg: rollout_chunk(
+                    s, m, p, h, icfg, ENGINE_STEPS)))
+            t = timeit(chunk, st, met, sp, horizon)
             base = t if base is None else base
             emit(
                 f"neighbor_{impl}_slots{n_slots}", t * 1e6,
-                f"{STEPS/t:.0f}_steps_per_s "
-                f"{STEPS*n_slots/t:.0f}_veh_steps_per_s "
+                f"{1e3 * t / ENGINE_STEPS:.3f}_ms_per_step "
+                f"{ENGINE_STEPS * N_BATCH * n_slots / t:.0f}_veh_steps_per_s "
                 f"speedup_vs_reference={base/t:.2f}x",
             )

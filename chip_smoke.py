@@ -288,6 +288,7 @@ def phase_sweep(args, work, mesh):
     """Step 1: the supervised sweep at the deployment's width."""
     import jax
     from repro.core.fleet import format_completion_table
+    from repro.core.neighbors import resolve_impl
 
     root = os.path.join(work, "sweep")
     cfg = sweep_config(INSTANCES, args.seed)
@@ -295,7 +296,8 @@ def phase_sweep(args, work, mesh):
     log(f"sweep: {cfg.n_instances} instances x {SLOTS} slots, "
         f"{len(cfg.scenarios)} scenarios, {WORKERS} workers, {STEPS} steps "
         f"in {CHUNK}-step chunks, fail_prob {FAIL_PROB}, seed {cfg.seed}, "
-        f"{cfg.sim.neighbor_impl} engine")
+        f"{resolve_impl(cfg.sim.neighbor_impl, SLOTS, jax.default_backend())}"
+        " engine")
     t0 = time.perf_counter()
     with CompileCount() as built:
         _, state, info, summary = supervised(
@@ -411,10 +413,10 @@ def phase_four_chip(args, work):
     devs = jax.devices()
     check(len(devs) >= 4, f"--four-chip needs 4 devices, found {len(devs)}")
     log_cut(FOUR_CHIP_INSTANCES)
-    # ``dense``, not the default ``sort``: the one-chip side pads each
-    # scenario group to all 32 workers, and on ``sort`` it took 202 s for
-    # 4 of its 8 chunks on a v5e. Engine parity is the default phase's
-    # check; this phase checks placement and equality across devices.
+    # ``dense``, pinned: the one-chip side pads each scenario group to all
+    # 32 workers, and on ``sort`` it took 202 s for 4 of its 8 chunks on
+    # a v5e. Engine parity is the default phase's check; this phase checks
+    # placement and equality across devices.
     cfg = sweep_config(FOUR_CHIP_INSTANCES, args.seed, neighbor_impl="dense")
     wpd = FOUR_CHIP_INSTANCES // 4
     log(f"four-chip: {cfg.n_instances} instances x {SLOTS} slots, "

@@ -6,8 +6,9 @@ two MOBIL candidate evaluations, the ramp-merge target search, the post-
 lane-change recompute, and the collision/TTC check — historically ~8
 independent O(N²) masked all-pairs scans per ``sim_step``.
 
-This module answers all of them through one API with three interchangeable
-implementations (``SimConfig.neighbor_impl``):
+This module answers all of them through one API with four interchangeable
+implementations (``SimConfig.neighbor_impl``), or ``auto`` (the default),
+which picks one from what the call can see (:func:`resolve_impl`):
 
 ``reference``
     The original per-query masked all-pairs scan (``neighbor_info``), one
@@ -40,6 +41,15 @@ every vehicle ``i``, the lead/follower of ``i`` *as if it were in lane l*.
 Arbitrary per-vehicle query-lane vectors then become O(N) gathers, so one
 table build serves every pre-move query of a step.
 
+``auto`` resolves from the static slot count ``N`` and the backend the
+program compiles for: ``dense`` on a TPU up to ``TPU_DENSE_MAX_SLOTS``
+slots, ``sort`` everywhere else. On a TPU v5e ``sort``'s ``searchsorted``
+binary searches are ``while`` loops of gathers, and the dense reduction
+beats them at every slot count measured there. CPU hosts keep ``sort``,
+the fastest there in one-instance rollouts at every measured ``N``
+(``benchmarks/throughput.py`` times the engines per slot count;
+``PERF.md`` holds the chip table).
+
 Every build and query runs under the ``neighbors`` named scope
 (``repro.core.trace.NEIGHBORS``), so a device profile attributes the
 engine's ops to it wherever it is called.
@@ -57,6 +67,15 @@ from repro.core.trace import NEIGHBORS
 INF = 1e9
 
 IMPLS = ("reference", "dense", "sort", "pallas")
+CHOICES = ("auto",) + IMPLS
+
+# Largest slot count at which ``auto`` picks ``dense`` on a TPU. This is
+# the edge of the measured range, not a crossover: on a TPU v5e ``dense``
+# beat ``sort`` 4.1-6.0x in per-step time at every slot count from 64 to
+# 2048 (48-instance highway_merge chunks, benchmarks/throughput.py), and
+# nothing above 2048 was measured. Other backends (CPU hosts) keep ``sort``
+# at every slot count.
+TPU_DENSE_MAX_SLOTS = 2048
 
 
 class Neighbors(NamedTuple):
@@ -223,8 +242,25 @@ def _pallas_tables(pos, lane, active, veh_len, n_lanes_total, interpret):
 # --------------------------------------------------------------------------
 
 def _check_impl(impl: str) -> None:
-    if impl not in IMPLS:
-        raise ValueError(f"neighbor_impl must be one of {IMPLS}, got {impl!r}")
+    if impl not in CHOICES:
+        raise ValueError(
+            f"neighbor_impl must be one of {CHOICES}, got {impl!r}")
+
+
+def resolve_impl(impl: str, n_slots: int, backend: str) -> str:
+    """The engine ``impl`` names at ``n_slots`` slots on ``backend``.
+
+    ``auto`` becomes ``dense`` on a TPU up to ``TPU_DENSE_MAX_SLOTS``
+    slots and ``sort`` above it or on any other backend; every other name
+    passes through. All engines answer bit-for-bit alike, so this picks a speed,
+    never a result.
+    """
+    _check_impl(impl)
+    if impl != "auto":
+        return impl
+    if backend == "tpu" and n_slots <= TPU_DENSE_MAX_SLOTS:
+        return "dense"
+    return "sort"
 
 
 @jax.named_scope(NEIGHBORS)
@@ -244,7 +280,7 @@ def build_tables(
     ``tables.query(q)`` — this is the single fused pass that replaces the
     per-query O(N²) scans.
     """
-    _check_impl(impl)
+    impl = resolve_impl(impl, pos.shape[-1], jax.default_backend())
     if impl == "reference":
         return _reference_tables(pos, lane, active, veh_len, n_lanes_total)
     if impl == "dense":
@@ -271,7 +307,7 @@ def query_lanes(
     Cheaper than ``build_tables`` when only one query is needed for a
     snapshot (the post-lane-change recompute).
     """
-    _check_impl(impl)
+    impl = resolve_impl(impl, pos.shape[-1], jax.default_backend())
     if impl in ("reference", "dense"):
         # a single query vector IS one masked all-pairs scan either way
         return Neighbors(*neighbor_info(pos, lane, active, veh_len, query_lane))

@@ -59,11 +59,14 @@ class SimConfig:
     record_every: int = 0      # 0 = no trajectory recording
     # neighborhood engine implementation (repro.core.neighbors):
     # "reference" (per-query O(N²) scans, the parity oracle), "dense"
-    # (fused single-pass O(N²)), "sort" (O(N log N) argsort+searchsorted;
-    # fastest at every measured n_slots on CPU hosts), "pallas" (the
-    # multi-query TPU kernel; interpret mode off-TPU). All four are
-    # bit-for-bit equivalent (tests/test_neighbors.py).
-    neighbor_impl: str = "sort"
+    # (fused single-pass O(N²)), "sort" (O(N log N) argsort+searchsorted),
+    # "pallas" (the multi-query TPU kernel; interpret mode off-TPU). All
+    # four are bit-for-bit equivalent (tests/test_neighbors.py). "auto"
+    # picks from n_slots and the backend (neighbors.resolve_impl): dense on
+    # a TPU up to 2048 slots (it beat sort at every n_slots measured
+    # there), sort above that and on CPU hosts, where sort was the fastest
+    # in one-instance rollouts at every measured n_slots.
+    neighbor_impl: str = "auto"
 
 
 class ScenarioParams(NamedTuple):

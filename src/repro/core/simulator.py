@@ -34,12 +34,13 @@ through the **neighborhood engine** (``repro.core.neighbors``), selected by
 - ``"dense"``     — fused dense path: one ``[N,N]`` pairwise
   materialization per state snapshot, per-lane tables derived in a single
   batched reduction; every query becomes an O(N) gather.
-- ``"sort"``      — O(N log N) (default): stable per-lane argsorts of
-  positions per snapshot, queries answered by searchsorted adjacency.
-  Fastest at every measured ``n_slots`` on CPU
-  (see ``benchmarks/throughput.py``).
+- ``"sort"``      — O(N log N): stable per-lane argsorts of positions per
+  snapshot, queries answered by searchsorted adjacency.
 - ``"pallas"``    — the generalized multi-query TPU kernel
   (``repro.kernels.idm.neighbor_kernel``; interpret mode off-TPU).
+- ``"auto"``      — the default: ``dense`` on a TPU up to 2048 slots,
+  ``sort`` above that and on CPU hosts
+  (``repro.core.neighbors.resolve_impl``).
 
 ``sim_step`` performs exactly **two** neighborhood constructions per step:
 one for the pre-move snapshot (serving the own-lane, MOBIL and merge

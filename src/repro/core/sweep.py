@@ -73,6 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core.neighbors import resolve_impl
 from repro.core.record import RecordConfig, TraceBuffer, batch_zeros
 from repro.core.scenario import SimConfig, ScenarioParams
 from repro.core.scenarios import get_scenario
@@ -693,9 +694,12 @@ class SweepRunner:
             return state._replace(done=done, chunk=state.chunk + 1)
 
     def _count_slot_steps(self, rows: int) -> None:
-        """Counter ``sweep.slot_steps``: what one group or block computes."""
-        count("sweep.slot_steps",
-              rows * self.cfg.chunk_steps * self.cfg.sim.n_slots)
+        """Counter ``sweep.slot_steps``: what one group or block computes,
+        with ``impl`` the neighbour engine that computes it."""
+        sim = self.cfg.sim
+        count("sweep.slot_steps", rows * self.cfg.chunk_steps * sim.n_slots,
+              impl=resolve_impl(sim.neighbor_impl, sim.n_slots,
+                                jax.default_backend()))
 
     def _run_block(self, state: SweepState, bp: BlockPlan) -> SweepState:
         """Gather per-device blocks, run ONE sharded call, scatter back.

@@ -27,7 +27,8 @@ The sweep's spans (``docs/ARCHITECTURE.md``, "Spans and counters"):
   ``fleet.audit``, ``fleet.journal``, each with ``chunk=`` the chunk it
   serves;
 - counter ``sweep.slot_steps``: slot-steps one group or block computes
-  (rows including padding x chunk steps x slots).
+  (rows including padding x chunk steps x slots), with ``impl=`` the
+  neighbour engine they ran on (``auto`` resolved).
 
 On the device, ``sim_step`` names its phases with ``jax.named_scope``
 (``neighbors``, ``longitudinal``, ``lane_change``, ``spawn``) and the

@@ -180,6 +180,7 @@ def main() -> None:
     # heavy imports AFTER the device-count flag is in place
     from repro.core.aggregate import aggregate_metrics, metrics_to_records
     from repro.core.fleet import format_completion_table, run_supervised
+    from repro.core.neighbors import CHOICES
     from repro.core.record import RecordConfig
     from repro.core.scenario import SimConfig
     from repro.core.scenarios import list_scenarios
@@ -209,9 +210,10 @@ def main() -> None:
                          "repacked compiled calls (no lax.switch tax), "
                          "switch = one vmapped-switch compile, auto = "
                          "grouped iff the scenario roster is mixed")
-    ap.add_argument("--neighbor-impl", default="sort",
-                    choices=["reference", "dense", "sort", "pallas"],
-                    help="neighborhood engine implementation")
+    ap.add_argument("--neighbor-impl", default="auto", choices=CHOICES,
+                    help="neighborhood engine implementation; auto = dense "
+                         "on a TPU up to 2048 slots, sort above that and on "
+                         "CPU hosts")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--vary-horizon", action="store_true")
     ap.add_argument("--fail-prob", type=float, default=0.0,

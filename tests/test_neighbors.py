@@ -11,10 +11,14 @@ import pytest
 
 from repro.core import SimConfig, init_state, sample_scenario_params
 from repro.core.neighbors import (
+    CHOICES,
     IMPLS,
+    TPU_DENSE_MAX_SLOTS,
+    _check_impl,
     build_tables,
     neighbor_info,
     query_lanes,
+    resolve_impl,
 )
 from repro.core.simulator import sim_step
 
@@ -40,7 +44,7 @@ def _impl_kwargs(impl):
     return {"interpret": True} if impl == "pallas" else {}
 
 
-@pytest.mark.parametrize("impl", [i for i in IMPLS if i != "reference"])
+@pytest.mark.parametrize("impl", [i for i in CHOICES if i != "reference"])
 @pytest.mark.parametrize("n", [8, 16, 48, 200])
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_tables_match_oracle_bitwise(impl, n, seed):
@@ -57,7 +61,7 @@ def test_tables_match_oracle_bitwise(impl, n, seed):
             )
 
 
-@pytest.mark.parametrize("impl", list(IMPLS))
+@pytest.mark.parametrize("impl", list(CHOICES))
 @pytest.mark.parametrize("seed", [0, 1])
 def test_query_lanes_match_oracle_bitwise(impl, seed):
     n = 64
@@ -94,8 +98,58 @@ def test_tables_on_live_simulator_states():
 
 def test_unknown_impl_raises():
     pos, lane, active = _rand_world(jax.random.key(0), 8)
+    _check_impl("auto")
+    with pytest.raises(ValueError, match="neighbor_impl"):
+        _check_impl("quadtree")
     with pytest.raises(ValueError, match="neighbor_impl"):
         build_tables(pos, lane, active, 4.5, L, "quadtree")
+    with pytest.raises(ValueError, match="neighbor_impl"):
+        query_lanes(pos, lane, active, 4.5, lane, "quadtree",
+                    n_lanes_total=L)
+    with pytest.raises(ValueError, match="neighbor_impl"):
+        resolve_impl("quadtree", 64, "tpu")
+
+
+# The table as measured on a TPU v5e (PERF.md): dense up to 2048 slots
+# there, sort above it and on every other backend.
+@pytest.mark.parametrize("impl,n_slots,backend,want", [
+    ("auto", 16, "tpu", "dense"),
+    ("auto", 64, "tpu", "dense"),
+    ("auto", 256, "tpu", "dense"),
+    ("auto", 2048, "tpu", "dense"),
+    ("auto", 2049, "tpu", "sort"),
+    ("auto", 4096, "tpu", "sort"),
+    ("auto", 64, "cpu", "sort"),
+    ("auto", 256, "cpu", "sort"),
+    ("auto", 64, "gpu", "sort"),
+    *[(impl, n, b, impl) for impl in IMPLS for n in (64, 4096)
+      for b in ("tpu", "cpu")],
+])
+def test_resolve_impl_crossover_table(impl, n_slots, backend, want):
+    assert TPU_DENSE_MAX_SLOTS == 2048
+    assert resolve_impl(impl, n_slots, backend) == want
+
+
+@pytest.mark.parametrize("n,engine", [(64, "dense"), (2049, "sort")])
+def test_auto_on_tpu_traces_the_resolved_engine(monkeypatch, n, engine):
+    """On a TPU ``auto`` traces exactly the engine it resolves to, and the
+    post-change recompute on ``dense`` stays one [N,N] scan (no table)."""
+    pos, lane, active = _rand_world(jax.random.key(3), n)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def jaxprs(impl):
+        tabs = jax.make_jaxpr(lambda p, l, a: build_tables(
+            p, l, a, 4.5, L, impl))(pos, lane, active)
+        one = jax.make_jaxpr(lambda p, l, a: query_lanes(
+            p, l, a, 4.5, l, impl, n_lanes_total=L))(pos, lane, active)
+        return str(tabs), str(one)
+
+    auto, pinned = jaxprs("auto"), jaxprs(engine)
+    assert auto == pinned
+    assert ("sort[" in auto[0]) == (engine == "sort")
+    if engine == "dense":
+        assert auto[1] == str(jax.make_jaxpr(
+            lambda p, l, a: neighbor_info(p, l, a, 4.5, l))(pos, lane, active))
 
 
 @pytest.mark.parametrize("n_slots", [16, 48])

@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from repro.core.neighbors import TPU_DENSE_MAX_SLOTS, resolve_impl
 from repro.core.record import RecordConfig
 from repro.core.scenario import SimConfig
 from repro.core.scenarios import list_scenarios
@@ -63,10 +64,10 @@ def _no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def _cfg(n_instances, n_slots, mix=None):
+def _cfg(n_instances, n_slots, mix=None, impl="sort"):
     return SweepConfig(
         n_instances=n_instances, steps_per_instance=1200, chunk_steps=400,
-        sim=SimConfig(n_slots=n_slots, neighbor_impl="sort"),
+        sim=SimConfig(n_slots=n_slots, neighbor_impl=impl),
         vary_horizon=True,
         scenario_mix=tuple(list_scenarios()) if mix is None else mix,
         record=RecordConfig(record_every=10, k_slots=8),
@@ -103,8 +104,9 @@ def test_neighbor_kernel_compiles_with_mosaic(one_chip, n, q):
 
 
 def test_sort_chunk_program_fits_one_chip(one_chip):
-    """One grouped 400-step chunk of the default engine, recording on, at
-    256 instances x 256 slots."""
+    """One grouped 400-step chunk of the ``sort`` engine (what ``auto``
+    picks on CPU hosts and above 2048 slots), recording on, at 256
+    instances x 256 slots."""
     runner = SweepRunner(_cfg(256, 256))
     args, _ = _chunk_args(runner, one_chip)
     compiled = runner._roster_fns[0].lower(*args).compile()
@@ -114,11 +116,24 @@ def test_sort_chunk_program_fits_one_chip(one_chip):
     assert 0 < used < HBM_BYTES
 
 
-def test_four_chip_block_program_has_no_collectives(topo):
-    """The D=4 executor compiles as one program with no cross-chip
-    traffic inside the chunk."""
+def test_dense_chunk_program_fits_one_chip_at_its_largest(one_chip):
+    """The engine ``auto`` picks on a TPU at the largest slot count it
+    picks it for: one 400-step chunk of 48 instances, recording on."""
+    n_slots = TPU_DENSE_MAX_SLOTS
+    assert resolve_impl("auto", n_slots, "tpu") == "dense"
+    runner = SweepRunner(_cfg(48, n_slots, mix=("highway_merge",),
+                              impl="dense"))
+    args, _ = _chunk_args(runner, one_chip)
+    mem = runner._roster_fns[0].lower(*args).compile().memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert 0 < used < HBM_BYTES
+
+
+def _assert_block_program_has_no_collectives(topo, impl):
     mesh = jax.sharding.Mesh(np.asarray(topo.devices), ("workers",))
-    runner = SweepRunner(_cfg(64, 64, mix=("highway_merge",)), mesh=mesh)
+    runner = SweepRunner(_cfg(64, 64, mix=("highway_merge",), impl=impl),
+                         mesh=mesh)
     spread = NamedSharding(mesh, P("workers"))
     args, row_sid = _chunk_args(runner, spread)
     block_sid = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=spread)
@@ -128,6 +143,19 @@ def test_four_chip_block_program_has_no_collectives(topo):
     for op in ("all-reduce", "all-gather", "all-to-all",
                "collective-permute", "reduce-scatter"):
         assert op not in text, op
+
+
+def test_four_chip_block_program_has_no_collectives(topo):
+    """The D=4 executor compiles as one program with no cross-chip
+    traffic inside the chunk."""
+    _assert_block_program_has_no_collectives(topo, "sort")
+
+
+def test_four_chip_dense_block_program_has_no_collectives(topo):
+    """The same on ``dense``, the engine ``auto`` picks for the four-chip
+    cell's 64 slots on a TPU."""
+    assert resolve_impl("auto", 64, "tpu") == "dense"
+    _assert_block_program_has_no_collectives(topo, "dense")
 
 
 def test_controller_import_leaves_jax_unloaded():

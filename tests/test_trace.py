@@ -10,10 +10,12 @@ the listeners saw:
   inside the durable write that copies state out);
 - the ``sweep.slot_steps`` counter equals what the plans' ``take`` sizes
   give;
+- it names the neighbour engine the slot-steps ran on;
 - listening changes no bit of the sweep;
 - the lowered chunk program names the five device phases.
 """
 
+import dataclasses
 import json
 import os
 import re
@@ -30,6 +32,7 @@ from repro.ckpt import CheckpointManager
 from repro.core import SimConfig
 from repro.core.fault import FaultModel
 from repro.core.fleet import RunJournal, run_supervised
+from repro.core.neighbors import resolve_impl
 from repro.core.record import RecordConfig, batch_zeros
 from repro.core.simulator import rollout_chunk_rec
 from repro.core.sweep import SweepConfig, SweepRunner
@@ -52,13 +55,14 @@ class Listener:
     """Spans and counters as ``jax.monitoring`` delivers them."""
 
     def __init__(self):
-        self.spans, self.scalars = [], []
+        self.spans, self.scalars, self.scalar_meta = [], [], []
 
     def _span(self, name, t0, t1, **meta):
         self.spans.append((name, t0, t1, meta))
 
     def _scalar(self, name, value, **meta):
         self.scalars.append((name, value))
+        self.scalar_meta.append((name, meta))
 
     def __enter__(self):
         jax.monitoring.register_event_time_span_listener(self._span)
@@ -183,6 +187,25 @@ def test_slot_steps_counter_matches_the_plans(traced):
     got = [v for name, v in lis.scalars if name == "sweep.slot_steps"]
     assert len(got) == sum(len(ps) for ps in plans)
     assert sum(got) == want > 0
+
+
+@pytest.mark.parametrize("impl", ["auto", "dense"])
+def test_slot_steps_counter_names_the_resolved_engine(traced, impl):
+    """``sweep.slot_steps`` carries ``impl=``, the engine the slot-steps
+    ran on: ``auto`` resolved from the slot count and the backend, a
+    pinned engine as it is named."""
+    if impl == "auto":
+        lis = traced[0]
+        sim = CFG.sim
+    else:
+        sim = dataclasses.replace(CFG.sim, neighbor_impl=impl)
+        runner = SweepRunner(dataclasses.replace(CFG, sim=sim),
+                             workers_per_device=2)
+        with Listener() as lis:
+            runner.run_chunk(runner.init())
+    want = resolve_impl(sim.neighbor_impl, sim.n_slots, jax.default_backend())
+    metas = [m for name, m in lis.scalar_meta if name == "sweep.slot_steps"]
+    assert metas and all(m == {"impl": want} for m in metas), metas
 
 
 def test_listening_changes_no_bit_of_the_sweep(traced, tmp_path):
