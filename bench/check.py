@@ -8,15 +8,19 @@ recorded row (from the shards a sweep wrote, or from the trace buffer of a
 sweep still running). The reference rebuilds the same instances from the
 sweep seed and steps each to the step count the harness itself counted
 (never the program's own clock), so a chunk that did not advance an
-instance shows as ``steps_gap``.
+instance shows as ``steps_gap``. A scenario module of the reference may
+add counters (``COUNTERS``) and per-vehicle fields compared exactly
+(``EXACT``); they count as ``counter_gap`` and ``slot_mismatch``.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-# the counters compared exactly (vehicle-steps, exits, arrivals, ...);
-# reference name -> program SimMetrics field
+import reference
+
+# the counters every scenario has, compared exactly (vehicle-steps, exits,
+# arrivals, ...); reference name -> program SimMetrics field
 COUNTERS = {
     "throughput": "throughput", "spawned": "spawned",
     "speed_count": "speed_count", "collisions": "collisions",
@@ -25,6 +29,12 @@ COUNTERS = {
 }
 NUMBERS = ("steps_gap", "slot_mismatch", "counter_gap", "pos_gap_m",
            "vel_gap_mps", "series_gap", "speed_sum_rel_gap")
+
+
+def counter_names(scenario: str) -> dict:
+    """Reference name -> SimMetrics field of every counter compared for an
+    instance of ``scenario``: the shared ones and its module's own."""
+    return COUNTERS | reference.scenario(scenario).counters
 
 
 def sample(n: int, k: int, rng: np.random.Generator, eligible=None,
@@ -61,7 +71,8 @@ def compare(answers: dict, expected: dict) -> dict:
     """The numbers compared, over every sampled instance. ``answers`` and
     ``expected`` map instance id -> {"t", "veh", "counters", "series",
     "lane", "speed", "active"} (the program's counters under SimMetrics
-    names, the reference's under its own)."""
+    names, the reference's under its own), and ``expected`` also to the
+    instance's "scenario"."""
     out = dict.fromkeys(NUMBERS, 0.0)
     for i, ref in expected.items():
         got = answers[i]
@@ -70,12 +81,14 @@ def compare(answers: dict, expected: dict) -> dict:
         both = gv["active"] & rv["active"]
         out["slot_mismatch"] += int(np.sum(gv["active"] != rv["active"])
                                     + np.sum(both & (gv["lane"] != rv["lane"])))
+        for f in reference.scenario(ref["scenario"]).exact:
+            out["slot_mismatch"] += int(np.sum(both & (gv[f] != rv[f])))
         out["pos_gap_m"] = max(out["pos_gap_m"],
                                _gap(gv["pos"][both], rv["pos"][both]))
         out["vel_gap_mps"] = max(out["vel_gap_mps"],
                                  _gap(gv["vel"][both], rv["vel"][both]))
         gc, rc = got["counters"], ref["counters"]
-        for r_name, p_name in COUNTERS.items():
+        for r_name, p_name in counter_names(ref["scenario"]).items():
             out["counter_gap"] = max(out["counter_gap"],
                                      _gap(gc[p_name], rc[r_name]))
         s_ref = float(rc["speed_sum"])

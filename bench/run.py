@@ -85,6 +85,9 @@ def load_cell(name: str, overrides: dict | None = None):
     with open(os.path.join(BENCH, "traffic", cell["traffic"] + ".json")) as f:
         traffic = json.load(f)
     for key, part in (overrides or {}).items():
+        if not isinstance(part, dict):
+            cfg[key] = part
+            continue
         target = traffic if key == "traffic" else cfg.setdefault(key, {})
         target.update(part)
     metrics = [m for m in bench["per_layer"]
@@ -163,10 +166,11 @@ class Tracer:
         jax.profiler.stop_trace()
 
 
-def answers(state, ids, every: int, rows_from=None) -> dict:
-    """The program's answers for instances ``ids``: final vehicle state,
-    counters, and recorded rows (from ``rows_from`` shards when given,
-    else from the state's trace buffer)."""
+def answers(state, ids, every: int, rows_from=None, fields=()) -> dict:
+    """The program's answers for instances ``ids``: final vehicle state
+    (with the per-vehicle ``fields`` the reference's scenario modules
+    compare exactly), counters, and recorded rows (from ``rows_from``
+    shards when given, else from the state's trace buffer)."""
     import jax
     import jax.numpy as jnp
 
@@ -186,7 +190,7 @@ def answers(state, ids, every: int, rows_from=None) -> dict:
         out[i] = {
             "t": t,
             "veh": {k: np.asarray(getattr(sim, k)[j])
-                    for k in ("pos", "vel", "lane", "active")},
+                    for k in ("pos", "vel", "lane", "active", *fields)},
             "counters": {k: np.asarray(getattr(met, k)[j])
                          for k in met._fields},
             **rows,
@@ -211,12 +215,13 @@ def reference_view(ref: dict, as_program: bool = False) -> dict:
     """The reference's answers in the comparison's layout; with
     ``as_program`` its counters carry the program's names (for the control,
     where the reference stands in the program's place)."""
-    def names(c):
-        return ({p: c[r] for r, p in check.COUNTERS.items()}
+    def names(c, scenario):
+        return ({p: c[r] for r, p in check.counter_names(scenario).items()}
                 | {"speed_sum": c["speed_sum"], "min_ttc": c["min_ttc"]}
                 if as_program else c)
 
-    return {i: {"t": r["t"], "veh": r["veh"], "counters": names(r["counters"]),
+    return {i: {"scenario": r["scenario"], "t": r["t"], "veh": r["veh"],
+                "counters": names(r["counters"], r["scenario"]),
                 "series": r["series"], "lane": r["lane"], "speed": r["speed"],
                 "active": r["active"]} for i, r in ref.items()}
 
@@ -295,6 +300,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     mesh = make_host_mesh(max_workers=cell["chips"])
     used = list(mesh.devices.flat)
     every = cfg["record"]["record_every"]
+    fields = sorted({f for s in cfg["roster"]
+                     for f in reference.scenario(s).exact})
     rec = spans.Record(traced=trace)
     rec.slot_steps_per_row = scfg.chunk_steps * scfg.sim.n_slots
     rec.listen()
@@ -362,7 +369,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             attempted, failed = n * (last_end - fill), 0
             ids = check.sample(n, traffic["sample"], rng)
             targets = {i: closed * scfg.chunk_steps for i in ids}
-            got = answers(state, ids, every)
+            got = answers(state, ids, every, fields=fields)
             incomplete = 0
         else:
             cap = traffic["max_chunks"]
@@ -420,7 +427,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             targets = {i: 10**9 for i in ids}     # each to its own horizon
             got = answers(state, ids, every,
                           rows_from=shard_rows(os.path.join(root, "dataset"),
-                                               ids))
+                                               ids), fields=fields)
 
         t_window = rec.window[1] - rec.window[0]
         setup_s = rec.window[0] - T_START
