@@ -29,7 +29,7 @@ from repro.core.scenarios import get_scenario
 # shard writer — ordered exactly as records have always been keyed
 _METRIC_COLUMNS = (
     "throughput", "spawned", "mean_speed", "collisions", "merges_ok",
-    "ramp_blocked_steps", "lane_changes", "min_ttc", "steps",
+    "ramp_blocked_steps", "lane_changes", "min_ttc", "steps", "exits_missed",
 )
 _PARAM_COLUMNS = (
     "lambda_main", "lambda_ramp", "p_cav", "v0_mean", "aux0", "aux1",
@@ -69,6 +69,7 @@ def metrics_to_columns(
     cols["lane_changes"] = m.lane_changes.astype(np.int64)
     cols["min_ttc"] = m.min_ttc.astype(np.float64)
     cols["steps"] = m.steps.astype(np.int64)
+    cols["exits_missed"] = m.exits_missed.astype(np.int64)
     if scenario_ids is not None and scenario_names is not None:
         ids = np.asarray(jax.device_get(scenario_ids)).astype(np.int64)
         cols["scenario_id"] = ids
@@ -149,6 +150,7 @@ def _summarize(m: SimMetrics, sel: np.ndarray) -> dict[str, float]:
         "total_ramp_blocked_steps": int(m.ramp_blocked_steps[sel].sum()),
         "min_ttc": float(m.min_ttc[sel].min()),
         "total_sim_steps": int(total_steps),
+        "total_exits_missed": int(m.exits_missed[sel].sum()),
     }
 
 

@@ -56,6 +56,9 @@ class SimConfig:
     # merge gap acceptance
     merge_gap_front: float = 8.0
     merge_gap_rear: float = 10.0
+    # downstream discharge limit (m/s) past the zone's end, 0 = none; the
+    # us101_weave reads it past the off-ramp gore
+    discharge_speed: float = 0.0
     record_every: int = 0      # 0 = no trajectory recording
     # neighborhood engine implementation (repro.core.neighbors):
     # "reference" (per-query O(N²) scans, the parity oracle), "dense"

@@ -13,6 +13,10 @@ stop_and_go        ring road (wraps)       wrap follow, periodic      stopped_st
                                            brake pulse, no exits      min_ttc
 speed_limit_zone   straight pipe,          zone accel cap +           zone_veh_steps,
                    work zone               anticipatory braking       throughput
+us101_weave        5 lanes + auxiliary     route draw, route caps +   weave_moves,
+                   lane, on-ramp to        MOBIL veto, forced moves   exits_missed,
+                   off-ramp gore (weave)   both ways, gore exit,      aux_blocked_steps
+                                           discharge limit
 ================== ======================= ========================== =====================
 
 Registering a custom scenario::
@@ -95,11 +99,13 @@ from repro.core.scenarios.highway_merge import HighwayMerge
 from repro.core.scenarios.lane_drop import LaneDrop
 from repro.core.scenarios.stop_and_go import StopAndGo
 from repro.core.scenarios.speed_limit_zone import SpeedLimitZone
+from repro.core.scenarios.us101_weave import US101Weave
 
 register_scenario(HighwayMerge)
 register_scenario(LaneDrop)
 register_scenario(StopAndGo)
 register_scenario(SpeedLimitZone)
+register_scenario(US101Weave)
 
 __all__ = [
     "INF",
@@ -114,4 +120,5 @@ __all__ = [
     "LaneDrop",
     "StopAndGo",
     "SpeedLimitZone",
+    "US101Weave",
 ]

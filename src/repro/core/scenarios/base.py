@@ -27,14 +27,20 @@ of traced arrays; the scenario object itself is static under jit because
     — scenario-specific *mandatory* moves applied after MOBIL (gap-acceptance
     ramp merge, forced lane-drop exit, vetoes of illegal MOBIL targets).
 
-``boundary``  (four methods)
+``boundary``  (six methods)
     ``boundary_spawn(cfg, geom, sp) -> (lam, base_v0, lane_ids)`` — the
     demand process: which lanes spawn, at what rate, at what desired speed;
+    ``boundary_route(cfg, geom, sp, key, lane_ids)`` — each arrival's
+    ``SimState.route`` (0 through, 1 exit), or ``None`` for a scenario
+    without routes (nothing is drawn and every route stays 0);
     ``boundary_clamp(st, cfg, geom, pos, vel)`` — post-integration position
     rules (ramp hard end, ring wrap); ``boundary_exit(st, cfg, geom)`` —
-    the exit predicate; ``boundary_gauge(st, cfg, geom)`` — the scenario's
-    per-step congestion gauge (reported as ``SimMetrics.ramp_blocked_steps``
-    and renamed in records via ``metric_aliases``).
+    the exit predicate; ``boundary_missed(st, cfg, geom, exited)`` — how
+    many of the exits leave by the wrong way for their route
+    (``SimMetrics.exits_missed``); ``boundary_gauge(st, cfg, geom)`` — the
+    scenario's per-step congestion gauge (reported as
+    ``SimMetrics.ramp_blocked_steps`` and renamed in records via
+    ``metric_aliases``).
 
 ``SimMetrics`` is structurally identical across scenarios (a ``lax.switch``
 requirement); ``metric_aliases`` maps the generic field names onto what they
@@ -128,6 +134,20 @@ def end_wall_gauge(st, wall_pos, on_wall_lane):
     return jnp.sum(blocked.astype(jnp.int32))
 
 
+def speed_limit_mods(st, limit, held, approaching, entry, a):
+    """A speed limit: ``held`` vehicles' accel is capped by IDM's free-road
+    term toward ``limit``; ``approaching`` ones faster than it brake as if
+    behind a vehicle at ``entry`` moving at ``limit`` (layered via min)."""
+    a_limit = st.a_max * (1.0 - (st.vel / limit) ** 4)
+    a = jnp.where(held, jnp.minimum(a, a_limit), a)
+    a_approach = idm_accel(
+        st.vel, st.vel - limit, entry - st.pos,
+        st.v0, st.T, st.a_max, st.b_comf, st.s0,
+    )
+    return jnp.where(approaching & (st.vel > limit),
+                     jnp.minimum(a, a_approach), a)
+
+
 class Scenario:
     """Base scenario: a plain multi-lane pipe with default everything.
 
@@ -211,6 +231,13 @@ class Scenario:
         base_v0 = jnp.full((geom.n_lanes,), 1.0) * sp.v0_mean
         return sp.lambda_main, base_v0, lanes
 
+    def boundary_route(self, cfg: SimConfig, geom: RoadGeometry,
+                       sp: ScenarioParams, key: jax.Array, lanes):
+        """Route of an arrival in each spawn lane of ``lanes`` (0 through,
+        1 exit), drawn from ``key``; ``None``: the scenario has no routes,
+        draws nothing and leaves ``SimState.route`` at 0."""
+        return None
+
     def boundary_clamp(self, st, cfg: SimConfig, geom: RoadGeometry,
                        pos, vel):
         """Post-integration position/velocity rules (walls, ring wrap)."""
@@ -219,6 +246,12 @@ class Scenario:
     def boundary_exit(self, st, cfg: SimConfig, geom: RoadGeometry):
         """Exit predicate on the post-integration state."""
         return st.active & (st.pos > geom.road_len)
+
+    def boundary_missed(self, st, cfg: SimConfig, geom: RoadGeometry,
+                        exited):
+        """Vehicles among ``exited`` that leave by the wrong way for their
+        route (the ``exits_missed`` metric delta); none without routes."""
+        return jnp.zeros((), jnp.int32)
 
     def boundary_gauge(self, st, cfg: SimConfig, geom: RoadGeometry):
         """Scenario congestion gauge (vehicle-steps this step); reported as

@@ -31,7 +31,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.scenario import ScenarioParams, SimConfig
-from repro.core.scenarios.base import RoadGeometry, Scenario, idm_accel
+from repro.core.scenarios.base import (
+    RoadGeometry,
+    Scenario,
+    speed_limit_mods,
+)
 
 
 class SpeedLimitZone(Scenario):
@@ -67,25 +71,12 @@ class SpeedLimitZone(Scenario):
 
     def longitudinal_mods(self, st, cfg, geom, sp, query_lane, nb, a,
                           ctx=None):
-        limit = jnp.maximum(sp.aux0, 0.1)
-
-        # inside the zone: free-road IDM toward the limit speed caps accel
-        in_zone = (st.pos >= geom.zone_start) & (st.pos <= geom.zone_end)
-        a_limit = st.a_max * (1.0 - (st.vel / limit) ** 4)
-        a = jnp.where(in_zone, jnp.minimum(a, a_limit), a)
-
+        # inside the zone: free-road IDM toward the limit speed caps accel;
         # upstream anticipation: follow a virtual leader at the zone
         # entrance moving at the limit speed
-        before = st.pos < geom.zone_start
-        ent_gap = geom.zone_start - st.pos
-        a_approach = idm_accel(
-            st.vel, st.vel - limit, ent_gap,
-            st.v0, st.T, st.a_max, st.b_comf, st.s0,
-        )
-        a = jnp.where(
-            before & (st.vel > limit), jnp.minimum(a, a_approach), a
-        )
-        return a
+        in_zone = (st.pos >= geom.zone_start) & (st.pos <= geom.zone_end)
+        return speed_limit_mods(st, jnp.maximum(sp.aux0, 0.1), in_zone,
+                                st.pos < geom.zone_start, geom.zone_start, a)
 
     # ---------------- boundary: zone occupancy gauge ----------------
 

@@ -96,6 +96,7 @@ class SimState(NamedTuple):
     s0: jax.Array         # [N] f32
     politeness: jax.Array # [N] f32
     cooldown: jax.Array   # [N] i32 lane-change cooldown
+    route: jax.Array      # [N] i32 0 through, 1 exit (Scenario.boundary_route)
     key: jax.Array        # PRNG key
     t: jax.Array          # [] i32 step counter
 
@@ -114,13 +115,14 @@ class SimMetrics(NamedTuple):
     lane_changes: jax.Array    # [] i32
     min_ttc: jax.Array         # [] f32
     steps: jax.Array           # [] i32
+    exits_missed: jax.Array    # [] i32 route failures (boundary_missed)
 
     @staticmethod
     def zeros() -> "SimMetrics":
         z_i = jnp.zeros((), jnp.int32)
         z_f = jnp.zeros((), jnp.float32)
         return SimMetrics(z_i, z_i, z_f, z_f, z_i, z_i, z_i, z_i,
-                          jnp.asarray(INF, jnp.float32), z_i)
+                          jnp.asarray(INF, jnp.float32), z_i, z_i)
 
 
 def init_state(cfg: SimConfig, key: jax.Array) -> SimState:
@@ -147,6 +149,7 @@ def init_state(cfg: SimConfig, key: jax.Array) -> SimState:
         s0=zf + 2.0,
         politeness=zf + 0.3,
         cooldown=jnp.zeros((n,), jnp.int32),
+        route=jnp.zeros((n,), jnp.int32),
         key=key,
         t=jnp.zeros((), jnp.int32),
     )
@@ -299,6 +302,10 @@ def _spawn(st: SimState, cfg: SimConfig, geom: RoadGeometry, scn: Scenario,
     cav = u[1] < sp.p_cav
     new_v0 = base_v0 * (0.9 + 0.2 * u[2])
     dp = driver_params(cav, kj, n_spawn_lanes)
+    # split(key) gave fold_in(key, 0) and fold_in(key, 1): the route's key
+    # is the next one, so it moves no other draw
+    route = scn.boundary_route(cfg, geom, sp, jax.random.fold_in(key, 2),
+                               lanes)
     # headway-derived entry speed uses the NEW driver's just-drawn headway
     # (the slot may still hold a previous occupant's stale T)
     init_v = jnp.minimum(new_v0, nearest / jnp.maximum(dp["T"], 0.5))
@@ -319,6 +326,8 @@ def _spawn(st: SimState, cfg: SimConfig, geom: RoadGeometry, scn: Scenario,
         s0=put(st.s0, dp["s0"]),
         politeness=put(st.politeness, dp["politeness"]),
     )
+    if route is not None:
+        st = st._replace(route=put(st.route, route))
     return st, jnp.sum(ok.astype(jnp.int32))
 
 
@@ -397,9 +406,11 @@ def sim_step(
         crashed = st.active & hl2 & (lg2 < 0.0)
         n_crash = jnp.sum(crashed.astype(jnp.int32))
 
-        # 5. exits (scenario predicate; a ring has none)
+        # 5. exits (scenario predicate; a ring has none), and the route
+        #    failures among them, before the exits leave
         exited = scn.boundary_exit(st, cfg, geom)
         n_out = jnp.sum(exited.astype(jnp.int32))
+        n_missed = scn.boundary_missed(st, cfg, geom, exited)
         active = st.active & ~exited & ~crashed
         st = st._replace(active=active, pos=jnp.where(active, st.pos, -INF))
 
@@ -431,6 +442,7 @@ def sim_step(
         lane_changes=n_lc,
         min_ttc=min_ttc,
         steps=jnp.ones((), jnp.int32),
+        exits_missed=n_missed,
     )
     return st, delta
 
@@ -447,6 +459,7 @@ def _acc(m: SimMetrics, d: SimMetrics) -> SimMetrics:
         lane_changes=m.lane_changes + d.lane_changes,
         min_ttc=jnp.minimum(m.min_ttc, d.min_ttc),
         steps=m.steps + d.steps,
+        exits_missed=m.exits_missed + d.exits_missed,
     )
 
 

@@ -31,9 +31,11 @@ The sweep's spans (``docs/ARCHITECTURE.md``, "Spans and counters"):
   neighbour engine they ran on (``auto`` resolved).
 
 On the device, ``sim_step`` names its phases with ``jax.named_scope``
-(``neighbors``, ``longitudinal``, ``lane_change``, ``spawn``) and the
-chunk rollout names its recorder (``record``): a profile attributes each
-device op to the phase in its ``op_name``.
+(``neighbors``, ``longitudinal``, ``lane_change``, ``spawn``), the chunk
+rollout names its recorder (``record``), and a scenario with routes names
+its route rules (``weave``: the route caps, vetoes, forced moves and the
+missed-exit tally, inside the other phases): a profile attributes each
+device op to the innermost phase in its ``op_name``.
 """
 
 from __future__ import annotations
@@ -43,8 +45,8 @@ import time
 import jax
 
 # the device phases' scope names, as ``jax.named_scope`` writes them
-NEIGHBORS, LONGITUDINAL, LANE_CHANGE, SPAWN, RECORD = PHASES = (
-    "neighbors", "longitudinal", "lane_change", "spawn", "record")
+NEIGHBORS, LONGITUDINAL, LANE_CHANGE, SPAWN, RECORD, WEAVE = PHASES = (
+    "neighbors", "longitudinal", "lane_change", "spawn", "record", "weave")
 
 
 class span:

@@ -26,7 +26,8 @@ from repro.core.simulator import SimMetrics
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
                        "aggregate_schema.json")
-ROSTER = ("highway_merge", "lane_drop", "stop_and_go", "speed_limit_zone")
+ROSTER = ("highway_merge", "lane_drop", "stop_and_go", "speed_limit_zone",
+          "us101_weave")
 N = 8
 
 

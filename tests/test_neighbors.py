@@ -23,16 +23,20 @@ from repro.core.neighbors import (
 from repro.core.simulator import sim_step
 
 L = 4  # 3 main lanes + ramp
+# (slot count, lane tables): the merge's 3 + 1 lanes, and the US-101
+# weave's 5 + 1 (ids keep the slot count alone for 3 + 1)
+SIZES = [pytest.param(n, L, id=str(n)) for n in (8, 16, 48, 200)] + [
+    pytest.param(n, 6, id=f"{n}x6") for n in (48, 200)]
 FIELDS = ("lead_idx", "lead_gap", "has_lead", "foll_idx", "foll_gap",
           "has_foll")
 
 
-def _rand_world(key, n, p_act=0.8):
+def _rand_world(key, n, p_act=0.8, lanes=L):
     """Random world with forced exact position ties (the argmin/stable-sort
     tie-break edge case) and inactive slots."""
     ks = jax.random.split(key, 3)
     pos = jax.random.uniform(ks[0], (n,), jnp.float32, 0.0, 900.0)
-    lane = jax.random.randint(ks[1], (n,), 0, L)
+    lane = jax.random.randint(ks[1], (n,), 0, lanes)
     if n > 4:
         pos = pos.at[1].set(pos[0]).at[4].set(pos[0])
         lane = lane.at[1].set(lane[0]).at[4].set(lane[0])
@@ -45,12 +49,14 @@ def _impl_kwargs(impl):
 
 
 @pytest.mark.parametrize("impl", [i for i in CHOICES if i != "reference"])
-@pytest.mark.parametrize("n", [8, 16, 48, 200])
+@pytest.mark.parametrize("n,lanes", SIZES)
 @pytest.mark.parametrize("seed", [0, 1, 2])
-def test_tables_match_oracle_bitwise(impl, n, seed):
-    pos, lane, active = _rand_world(jax.random.key(seed * 1000 + n), n)
-    tabs = build_tables(pos, lane, active, 4.5, L, impl, **_impl_kwargs(impl))
-    for l in range(L):
+def test_tables_match_oracle_bitwise(impl, n, lanes, seed):
+    pos, lane, active = _rand_world(jax.random.key(seed * 1000 + n), n,
+                                    lanes=lanes)
+    tabs = build_tables(pos, lane, active, 4.5, lanes, impl,
+                        **_impl_kwargs(impl))
+    for l in range(lanes):
         q = jnp.full((n,), l, jnp.int32)
         ref = neighbor_info(pos, lane, active, 4.5, q)
         got = tabs.query(q)
@@ -152,10 +158,17 @@ def test_auto_on_tpu_traces_the_resolved_engine(monkeypatch, n, engine):
             lambda p, l, a: neighbor_info(p, l, a, 4.5, l))(pos, lane, active))
 
 
-@pytest.mark.parametrize("n_slots", [16, 48])
-def test_sim_step_equivalent_across_impls(n_slots):
+# the US-101 weave's geometry (bench/configs/us101.json): 5 + 1 lane tables
+WEAVE = dict(scenario="us101_weave", n_lanes=5, road_len=640.0,
+             merge_start=60.0, merge_end=420.0, discharge_speed=4.0)
+
+
+@pytest.mark.parametrize("n_slots,world", [
+    pytest.param(16, {}, id="16"), pytest.param(48, {}, id="48"),
+    pytest.param(48, WEAVE, id="48-us101_weave")])
+def test_sim_step_equivalent_across_impls(n_slots, world):
     """End-to-end: identical trajectories for every neighbor_impl."""
-    base = SimConfig(n_slots=n_slots)
+    base = SimConfig(n_slots=n_slots, **world)
     sp = sample_scenario_params(jax.random.key(1), base)
     finals = {}
     for impl in IMPLS:

@@ -245,6 +245,7 @@ def _legacy_sim_step(st, cfg, sp):
         lane_changes=n_lc,
         min_ttc=min_ttc,
         steps=jnp.ones((), jnp.int32),
+        exits_missed=jnp.zeros((), jnp.int32),
     )
     return st, delta
 

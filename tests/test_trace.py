@@ -12,7 +12,8 @@ the listeners saw:
   give;
 - it names the neighbour engine the slot-steps ran on;
 - listening changes no bit of the sweep;
-- the lowered chunk program names the five device phases.
+- the lowered chunk program names the device phases: the five shared
+  ones, and ``weave`` where the scenario has route rules.
 """
 
 import dataclasses
@@ -36,7 +37,7 @@ from repro.core.neighbors import resolve_impl
 from repro.core.record import RecordConfig, batch_zeros
 from repro.core.simulator import rollout_chunk_rec
 from repro.core.sweep import SweepConfig, SweepRunner
-from repro.core.trace import PHASES, count, span
+from repro.core.trace import PHASES, WEAVE, count, span
 from repro.data.shards import DatasetWriter
 
 CFG = SweepConfig(
@@ -215,18 +216,28 @@ def test_listening_changes_no_bit_of_the_sweep(traced, tmp_path):
     assert quiet_info["failure_events"] == info["failure_events"]
 
 
-def test_lowered_chunk_names_the_device_phases():
-    st = SweepRunner(CFG).init()
+@pytest.mark.parametrize("scenario,phases", [
+    ("highway_merge", set(PHASES) - {WEAVE}),
+    ("us101_weave", set(PHASES)),
+], ids=["highway_merge", "us101_weave"])
+def test_lowered_chunk_names_the_device_phases(scenario, phases):
+    """Every phase is named in a lowered chunk of a scenario that has it:
+    ``weave`` only where the scenario has route rules."""
+    cfg = dataclasses.replace(
+        CFG, sim=dataclasses.replace(CFG.sim, scenario=scenario),
+        scenario_mix=())
+    st = SweepRunner(cfg).init()
     one = jax.tree.map(lambda x: x[0], (st.sim, st.metrics, st.params,
                                         st.horizon))
     tr = jax.tree.map(lambda x: x[0],
-                      batch_zeros(CFG.record, CFG.steps_per_instance, 1))
+                      batch_zeros(cfg.record, cfg.steps_per_instance, 1))
     text = rollout_chunk_rec.lower(
-        *one, tr, cfg=CFG.sim, n_steps=CFG.chunk_steps, rec=CFG.record,
+        *one, tr, cfg=cfg.sim, n_steps=cfg.chunk_steps, rec=cfg.record,
     ).as_text(debug_info=True)
     scopes = {part for loc in re.findall(r'loc\("([^"]*)"', text)
               for part in loc.split("/")[:-1]}
-    assert set(PHASES) <= scopes
+    assert phases <= scopes
+    assert (WEAVE in scopes) == (WEAVE in phases)
 
 
 def test_block_executor_spans_on_four_devices():
